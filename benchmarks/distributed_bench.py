@@ -1,7 +1,8 @@
 """Beyond-paper: distributed-index scaling (sample-sort build + exact
 query) across host-device shard counts.
 
-Runs in subprocesses (device count is locked per process).  Reports build
+Runs in subprocesses held to the CPU (device count is locked per process,
+and a child must never reach for a chip its parent may hold).  Reports build
 and query wall time per shard count plus partition balance — the paper's
 "parallel UB-tree building" future work, measured.
 """
@@ -47,6 +48,7 @@ print(f"RESULT {t_build*1e6:.1f} {t_query*1e6:.1f} "
 def main() -> None:
     for d in (1, 2, 4, 8):
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
         env["PYTHONPATH"] = str(REPO / "src")
         r = subprocess.run(

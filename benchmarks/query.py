@@ -168,14 +168,15 @@ def bench_batched_query(n: int = 16000,
 def _mesh_sweep_impl(n: int = 64000, nq: int = 64, k: int = 10,
                      shards: int = 4, *, smoke: bool = False):
     """QPS vs device count for the device-resident sharded scan: one
-    threaded reference, then the mesh launch at D in {1, 2, 4} devices
-    (``COCONUT_MESH_DEVICES`` caps the scan mesh below the forced host
-    device count, so one 4-device process sweeps the whole curve).
-    Must run under >= 4 devices; answers are parity-checked against the
-    threaded fan-out at every point.  Returns (rows, gates)."""
+    threaded reference, then the mesh launch at D in {1, 2, 4} devices,
+    as far as this process holds them (``COCONUT_MESH_DEVICES`` caps the
+    scan mesh below the device count, so one process sweeps the whole
+    curve).  Answers are parity-checked against the threaded fan-out at
+    every point.  Returns (rows, gates); the 4-device gate exists only
+    where 4 devices were swept."""
     import jax
     from repro.distributed.sharded_lsm import ShardedCoconutLSM
-    assert jax.device_count() >= 4, jax.device_count()
+    sweep = [d for d in (1, 2, 4) if d <= jax.device_count()]
     cfg = cfg_for()
     raw = np.asarray(dataset(n))
     queries = np.asarray(dataset(nq, seed=11))
@@ -193,7 +194,7 @@ def _mesh_sweep_impl(n: int = 64000, nq: int = 64, k: int = 10,
     rows.append((f"query/mesh_sweep/threaded/{tag}", us_t,
                  f"qps={nq / (us_t / 1e6):.1f};shards={shards}"))
     us_mesh = {}
-    for d in (1, 2, 4):
+    for d in sweep:
         os.environ["COCONUT_MESH_DEVICES"] = str(d)
         try:
             eng._mesh_engine = None     # re-pin under the device cap
@@ -212,30 +213,36 @@ def _mesh_sweep_impl(n: int = 64000, nq: int = 64, k: int = 10,
                      f"qps={nq / (us / 1e6):.1f};devices={d};"
                      f"speedup={us_t / us:.2f}x"))
     eng.close()
-    speedup = us_t / us_mesh[4]
-    gates = [{"name": "mesh_vs_threaded_d4", "value": speedup,
-              "min": 1.3}]
-    if smoke:
-        # the scaling claim, asserted at bench time: with >= 2 devices
-        # the one-launch scan must beat the threaded fan-out outright
-        assert us_mesh[2] < us_t, (us_mesh, us_t)
-        assert speedup >= 1.3, (us_mesh, us_t)
+    gates = []
+    if 4 in us_mesh:
+        speedup = us_t / us_mesh[4]
+        gates = [{"name": "mesh_vs_threaded_d4", "value": speedup,
+                  "min": 1.3}]
+        if smoke:
+            # the scaling claim, asserted at bench time: with >= 2
+            # devices the one-launch scan must beat the threaded fan-out
+            assert us_mesh[2] < us_t, (us_mesh, us_t)
+            assert speedup >= 1.3, (us_mesh, us_t)
     for name, us, derived in rows:
         emit(name, us, derived)
     return rows, gates
 
 
 def bench_mesh_devices(*, smoke: bool = False):
-    """Run the mesh device sweep, re-execing into a 4-forced-host-device
-    child when this process's device topology is already locked smaller
-    (device count is fixed at first jax init)."""
+    """Run the mesh device sweep over the devices this process holds.
+
+    An accelerator belongs to the process that touched it first, so on
+    one the sweep stays here, over the chips it has.  Only on the CPU,
+    whose device count is fixed at first jax init, does it re-exec into
+    a child held to the CPU with 4 forced host devices."""
     import jax
-    if jax.device_count() >= 4:
+    if jax.default_backend() != "cpu" or jax.device_count() >= 4:
         _rows, gates = _mesh_sweep_impl(smoke=smoke)
         return gates
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
         out_path = f.name
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env.setdefault("PYTHONPATH", str(ROOT / "src"))
     cmd = [sys.executable, "-m", "benchmarks.query",

@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from scipy.special import ndtri
 
 from . import keys as K
 
@@ -36,6 +37,9 @@ __all__ = [
     "invsax_keys",
     "mindist_sq",
     "mindist_sq_batch",
+    "pairwise_sum",
+    "sum_sq",
+    "sq_dist",
     "euclidean_sq",
     "euclidean_sq_batch",
 ]
@@ -78,19 +82,7 @@ def _breakpoints_np(bits: int) -> np.ndarray:
     """
     card = 1 << bits
     qs = np.arange(1, card, dtype=np.float64) / card
-    from scipy.special import ndtri as _ndtri  # type: ignore
-    return _ndtri(qs).astype(np.float32)
-
-
-try:  # scipy is optional in this container: fall back to jax.scipy
-    import scipy.special  # noqa: F401
-except Exception:  # pragma: no cover - environment dependent
-    @functools.lru_cache(maxsize=None)
-    def _breakpoints_np(bits: int) -> np.ndarray:  # type: ignore
-        card = 1 << bits
-        qs = np.arange(1, card, dtype=np.float64) / card
-        import jax.scipy.special as jsp
-        return np.asarray(jsp.ndtri(jnp.asarray(qs)), dtype=np.float32)
+    return ndtri(qs).astype(np.float32)
 
 
 def breakpoints(bits: int) -> jax.Array:
@@ -178,16 +170,52 @@ def mindist_sq_batch(query_paas: jax.Array, codes: jax.Array,
     return (cfg.series_len / cfg.segments) * jnp.sum(d * d, axis=-1)
 
 
+def pairwise_sum(s: jax.Array) -> jax.Array:
+    """Sum over the last axis, added in one fixed order.
+
+    A pairwise tree of elementwise adds: element ``i`` meets element
+    ``i + h`` as ``h`` halves from the width (zero-padded to a power of
+    two) down to 1.  Every output word is then the same sequence of IEEE
+    adds whatever the other dimensions are.  ``jnp.sum`` leaves the order
+    to the compiler, which may choose it per shape, so a row's distance
+    could change with the batch size or the block it was verified in.
+    Every distance an answer carries is added in this order, including
+    inside the Pallas kernels.
+    """
+    n = s.shape[-1]
+    p = 1 << (n - 1).bit_length()
+    if p != n:
+        s = jnp.pad(s, [(0, 0)] * (s.ndim - 1) + [(0, p - n)])
+    while s.shape[-1] > 1:
+        h = s.shape[-1] // 2
+        s = s[..., :h] + s[..., h:]
+    return s[..., 0]
+
+
+def sum_sq(diff: jax.Array) -> jax.Array:
+    """Sum of squares over the last axis: each square rounded to f32 on
+    its own, then :func:`pairwise_sum`.  The ``maximum`` with zero leaves
+    every square (and a NaN) as it is, but stands between the multiply
+    and the first add, so a compiler cannot contract the two into a fused
+    multiply-add (which rounds once, and is formed or not depending on
+    the fusion)."""
+    return pairwise_sum(jnp.maximum(diff * diff, 0.0))
+
+
+@jax.jit
+def sq_dist(series: jax.Array, queries: jax.Array) -> jax.Array:
+    """Squared ED between broadcast-compatible ``[..., L]`` arrays."""
+    return sum_sq(series - queries)
+
+
 def euclidean_sq(query: jax.Array, series: jax.Array) -> jax.Array:
     """Squared ED between query ``[L]`` and series ``[N, L]`` -> ``[N]``."""
-    diff = series - query[None, :]
-    return jnp.sum(diff * diff, axis=-1)
+    return sq_dist(series, query[None, :])
 
 
 def euclidean_sq_batch(queries: jax.Array, series: jax.Array) -> jax.Array:
     """Squared ED between queries ``[Q, L]`` and series ``[N, L]`` -> ``[Q, N]``."""
-    diff = series[None, :, :] - queries[:, None, :]
-    return jnp.sum(diff * diff, axis=-1)
+    return sq_dist(series[None, :, :], queries[:, None, :])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

@@ -37,6 +37,14 @@ __all__ = ["CoconutTree", "build", "approx_search", "exact_search",
            "save", "load"]
 
 
+@jax.jit
+def take_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` as one program.  Eager indexing compiles its index
+    arithmetic (wrap-around of negative indices) as programs of their
+    own, for every shape a scan gathers."""
+    return x[idx]
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class CoconutTree:
@@ -81,8 +89,8 @@ class CoconutTree:
     def series(self, idx: jax.Array) -> jax.Array:
         """Fetch raw series rows for sorted-order indices ``idx``."""
         if self.raw is not None:
-            return self.raw[idx]
-        return self.raw_ref[self.offsets[idx]]
+            return take_rows(self.raw, idx)
+        return take_rows(self.raw_ref, take_rows(self.offsets, idx))
 
     @property
     def fences(self) -> jax.Array:
@@ -101,6 +109,59 @@ def _report_column(tree: CoconutTree):
     when the tree carries ids (LSM runs), else the position in the
     original raw file (standalone trees keep their historical contract)."""
     return tree.ids if tree.ids is not None else tree.offsets
+
+
+# A sort is among the slowest programs to compile on the TPU (a minute
+# or more at run sizes), and eager code compiles every gather and index
+# op of a flush or merge anew for each run size.  So: the build sorts at
+# a power-of-two row count, which run sizes share; merges of sorted runs
+# never sort; and each step's gathers are one program per shape.
+
+@jax.jit
+def _lexsort_padded(keys: jax.Array) -> jax.Array:
+    return K.lexsort_keys(keys)
+
+
+def _key_order(keys: jax.Array) -> jax.Array:
+    """Stable lexicographic order of ``keys``, sorted with all-ones
+    sentinel rows appended up to the next power of two: the sort is
+    stable, so every sentinel lands after every real row and the first
+    ``n`` entries are the order of ``keys`` alone."""
+    n = keys.shape[0]
+    pad = (1 << max(0, (n - 1).bit_length())) - n
+    if pad:
+        keys = jnp.pad(keys, ((0, pad), (0, 0)),
+                       constant_values=np.uint32(0xFFFFFFFF))
+    return _lexsort_padded(keys)[:n]
+
+
+@jax.jit
+def _gather_cols(order: jax.Array, keys: jax.Array, cols: dict):
+    """``keys`` and every column of ``cols`` gathered by ``order``."""
+    return keys[order], {name: c[order] for name, c in cols.items()}
+
+
+@jax.jit
+def _merge_cols(a_keys: jax.Array, b_keys: jax.Array, a_offs: jax.Array,
+                b_offs: jax.Array, a_cols: dict, b_cols: dict):
+    """Merge two sorted runs' keys, offsets and columns into one sorted
+    run by rank, with no sort: row ``i`` of ``a`` goes after the rows of
+    ``b`` with smaller keys, row ``j`` of ``b`` after the rows of ``a``
+    with keys not larger — the stable sort of ``a`` then ``b``.  The
+    offsets of the merged view address a virtual concatenated raw file,
+    so ``b``'s are shifted past ``a``'s rows."""
+    na, nb = a_keys.shape[0], b_keys.shape[0]
+    ia = jnp.arange(na, dtype=jnp.int32)
+    ib = jnp.arange(nb, dtype=jnp.int32)
+    pos_a = ia + K.searchsorted_keys(b_keys, a_keys, side="left")
+    pos_b = ib + K.searchsorted_keys(a_keys, b_keys, side="right")
+    order = (jnp.zeros(na + nb, jnp.int32).at[pos_a].set(ia)
+             .at[pos_b].set(na + ib))
+    cols = {name: jnp.concatenate([a_cols[name], b_cols[name]])
+            for name in a_cols}
+    cols["offs"] = jnp.concatenate([a_offs, b_offs + na])
+    keys, cols = _gather_cols(order, jnp.concatenate([a_keys, b_keys]), cols)
+    return order, keys, cols
 
 
 def build(raw: jax.Array,
@@ -137,15 +198,24 @@ def build(raw: jax.Array,
         paas = jnp.asarray(paas, jnp.float32)
         codes = jnp.asarray(codes, jnp.uint8)
     keys = S.invsax_keys(codes, cfg)
-    order = K.lexsort_keys(keys)
-    keys = keys[order]
-    codes = codes[order]
-    paas = paas[order]
-    offsets = order.astype(jnp.int32)
-    ts = timestamps[order] if timestamps is not None else None
+    cols = {"codes": codes, "paas": paas}
+    if materialized:
+        cols["raw"] = raw
     # device ids inherit the default int width (x64 is disabled); the
     # int64 view lives host-side (np conversions, segment files, WAL)
-    ids_sorted = jnp.asarray(ids)[order] if ids is not None else None
+    if ids is not None:
+        cols["ids"] = jnp.asarray(ids)
+    if isinstance(timestamps, jax.Array):
+        cols["ts"] = timestamps
+    # the gather copies every column, the raw rows too: wait for the
+    # summaries and the sort first, so their buffers are freed before it
+    # claims its memory (queued behind them it ran a chip holding a 4 GiB
+    # collection out of device memory)
+    order = jax.block_until_ready(_key_order(keys))
+    keys, cols = _gather_cols(order, keys, cols)
+    ts = cols.get("ts")
+    if timestamps is not None and ts is None:     # host timestamps stay so
+        ts = timestamps[np.asarray(order)]
     if io is not None:
         io.seq_read(n)            # pass over the raw file (summarize)
         io.seq_write(n)           # write sorted summaries
@@ -155,10 +225,10 @@ def build(raw: jax.Array,
             io.seq_read(n)        # extra pass: co-sort raw into leaves
             io.seq_write(n)
     return CoconutTree(
-        keys=keys, codes=codes, paas=paas, offsets=offsets,
-        raw=raw[order] if materialized else None,
+        keys=keys, codes=cols["codes"], paas=cols["paas"],
+        offsets=order.astype(jnp.int32), raw=cols.get("raw"),
         raw_ref=None if materialized else raw,
-        timestamps=ts, ids=ids_sorted, cfg=cfg, leaf_size=leaf_size)
+        timestamps=ts, ids=cols.get("ids"), cfg=cfg, leaf_size=leaf_size)
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +369,7 @@ def _approx_candidates_batch(tree: CoconutTree, queries: jax.Array,
     idx = start[:, None] + jnp.arange(span, dtype=jnp.int32)[None, :]
     idx = jnp.clip(idx, 0, tree.n - 1)                   # [Q, span]
     cand = tree.series(idx)                              # [Q, span, L]
-    d = jnp.sum((cand - q[:, None, :]) ** 2, axis=-1)
+    d = S.sum_sq(cand - q[:, None, :])
     return d, idx
 
 
@@ -398,31 +468,26 @@ def merge_trees(a: CoconutTree, b: CoconutTree, *,
         raise ValueError("cannot merge trees with different summary configs")
     if a.materialized != b.materialized:
         raise ValueError("cannot merge materialized with non-materialized")
-    keys = jnp.concatenate([a.keys, b.keys])
-    codes = jnp.concatenate([a.codes, b.codes])
-    paas = jnp.concatenate([a.paas, b.paas])
-    # offsets in the merged view address a virtual concatenated raw file
-    offs = jnp.concatenate([a.offsets, b.offsets + a.n])
-    ts = None
+    a_cols = {"codes": a.codes, "paas": a.paas}
+    b_cols = {"codes": b.codes, "paas": b.paas}
     if a.timestamps is not None and b.timestamps is not None:
-        ts = jnp.concatenate([a.timestamps, b.timestamps])
-    ids = None
+        a_cols["ts"], b_cols["ts"] = a.timestamps, b.timestamps
     if a.ids is not None and b.ids is not None:
-        ids = jnp.concatenate([a.ids, b.ids])
-    order = K.lexsort_keys(keys)
-    raw = raw_ref = None
+        a_cols["ids"], b_cols["ids"] = a.ids, b.ids
+    raw_ref = None
     if a.materialized:
-        raw = jnp.concatenate([a.raw, b.raw])[order]
+        a_cols["raw"], b_cols["raw"] = a.raw, b.raw
     else:
         raw_ref = jnp.concatenate([a.raw_ref, b.raw_ref])
+    _order, keys, cols = _merge_cols(a.keys, b.keys, a.offsets, b.offsets,
+                                     a_cols, b_cols)
     if io is not None:
         io.seq_read(a.n + b.n)
         io.seq_write(a.n + b.n)
     return CoconutTree(
-        keys=keys[order], codes=codes[order], paas=paas[order],
-        offsets=offs[order].astype(jnp.int32), raw=raw, raw_ref=raw_ref,
-        timestamps=None if ts is None else ts[order],
-        ids=None if ids is None else ids[order],
+        keys=keys, codes=cols["codes"], paas=cols["paas"],
+        offsets=cols["offs"].astype(jnp.int32), raw=cols.get("raw"),
+        raw_ref=raw_ref, timestamps=cols.get("ts"), ids=cols.get("ids"),
         cfg=a.cfg, leaf_size=a.leaf_size)
 
 

@@ -9,6 +9,7 @@ code path is exercised without the 100GB download).
 """
 from __future__ import annotations
 
+import functools
 from typing import Iterator, Optional, Tuple
 
 import jax
@@ -17,8 +18,8 @@ import numpy as np
 
 from ..core.summarization import znormalize
 
-__all__ = ["random_walk", "sliding_windows", "synthetic_signal",
-           "series_batches", "query_workload"]
+__all__ = ["random_walk", "random_walk_blocks", "sliding_windows",
+           "synthetic_signal", "series_batches", "query_workload"]
 
 
 def random_walk(key: jax.Array, n: int, length: int = 256,
@@ -27,6 +28,20 @@ def random_walk(key: jax.Array, n: int, length: int = 256,
     steps = jax.random.normal(key, (n, length))
     x = jnp.cumsum(steps, axis=-1)
     return znormalize(x) if znorm else x
+
+
+@functools.partial(jax.jit, static_argnames=("n", "length", "block"))
+def random_walk_blocks(key: jax.Array, n: int, length: int = 256,
+                       block: int = 1 << 16) -> jax.Array:
+    """``n`` z-normalized random walks made on the device ``block`` rows
+    at a time in one program, so a collection that fills most of a
+    chip's memory needs little more than itself while it is made.
+    Block ``i`` is ``random_walk(fold_in(key, i), block, length)``."""
+    nb = -(-n // block)
+    out = jax.lax.map(
+        lambda i: random_walk(jax.random.fold_in(key, i), block, length),
+        jnp.arange(nb))
+    return out.reshape(nb * block, length)[:n]
 
 
 def synthetic_signal(key: jax.Array, total_len: int,

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
-
 __all__ = ["pipeline_forward"]
 
 
@@ -73,7 +71,7 @@ def pipeline_forward(mesh, stage_fn: Callable, n_stages: int,
     def call(stage_params, xs):
         pspec_params = jax.tree.map(
             lambda _: P(axis), stage_params)
-        fn = shard_map(
+        fn = jax.shard_map(
             run, mesh=mesh,
             in_specs=(pspec_params, P()),
             out_specs=P(), check_vma=False)

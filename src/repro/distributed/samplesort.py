@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .compat import shard_map
-
 from ..core import keys as K
 
 __all__ = ["sharded_sort", "splitters_from_sample", "local_topk_merge"]
@@ -72,13 +70,12 @@ def sharded_sort(mesh, keys: jax.Array, payload: jax.Array, *,
     n_words = keys.shape[1]
     pay_shape = payload.shape[1:]
 
-    if d == 1:                      # degenerate mesh: plain local sort
-        order = K.lexsort_keys(keys)
-        counts = jnp.asarray([keys.shape[0]], jnp.int32)
-        return keys[order], payload[order], counts
-
     def body(k_loc, p_loc):
         n_loc = k_loc.shape[0]
+        if d == 1:                  # degenerate mesh: plain local sort
+            order = K.lexsort_keys(k_loc)
+            return (k_loc[order], p_loc[order],
+                    jnp.full((1,), n_loc, jnp.int32))
         cap = int(cap_factor * n_loc)
         my = jax.lax.axis_index(axis)
 
@@ -139,8 +136,8 @@ def sharded_sort(mesh, keys: jax.Array, payload: jax.Array, *,
                  P(axis) if payload.ndim == 1
                  else P(axis, *([None] * (payload.ndim - 1))),
                  P(axis))
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     rk, rp, counts = fn(keys, payload)
     return rk, rp, counts
 
@@ -162,6 +159,6 @@ def local_topk_merge(mesh, dists: jax.Array, ids: jax.Array, k: int,
         return -neg2, i_all[idx2]
 
     from jax.sharding import PartitionSpec as P
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                       out_specs=(P(), P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                           out_specs=(P(), P()), check_vma=False)
     return fn(dists, ids)

@@ -30,7 +30,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core import keys as K
 from ..core import summarization as S
 from ..kernels import mesh_scan as _mesh
-from .compat import shard_map
 from .samplesort import sharded_sort
 
 __all__ = ["ShardedCoconutTree", "build_sharded", "distributed_exact_search",
@@ -71,8 +70,9 @@ def build_sharded(mesh, raw: jax.Array, cfg: S.SummaryConfig, *,
     d = mesh.shape[axis]
     n, L = raw.shape
     assert n % d == 0, f"N={n} must divide over {axis}={d}"
-    sh = NamedSharding(mesh, P(axis, None))
-    raw = jax.device_put(raw, sh)
+    # summarize before placing: the summaries and keys are row-wise, and
+    # jnp.searchsorted's scan is rejected on explicitly sharded inputs
+    raw = jnp.asarray(raw, jnp.float32)
     paas, codes = S.summarize(raw, cfg)
     keys = S.invsax_keys(codes, cfg)
     # payload rows: raw co-sorted with keys (materialized index) + the PAA /
@@ -81,7 +81,9 @@ def build_sharded(mesh, raw: jax.Array, cfg: S.SummaryConfig, *,
     if timestamps is not None:
         cols.append(jnp.asarray(timestamps, jnp.float32)[:, None])
     pay = jnp.concatenate(cols, axis=1)
-    skeys, spay, counts = sharded_sort(mesh, keys, pay, axis=axis,
+    sh = NamedSharding(mesh, P(axis, None))
+    skeys, spay, counts = sharded_sort(mesh, jax.device_put(keys, sh),
+                                       jax.device_put(pay, sh), axis=axis,
                                        cap_factor=cap_factor)
     if bool(jnp.any(counts < 0)):
         raise RuntimeError("sample-sort bucket overflow; raise cap_factor")
@@ -151,8 +153,7 @@ def distributed_exact_search_batch(tree: ShardedCoconutTree,
             # final bits from the one [Q, k, L] recompute both branches
             # share — the scan above only SELECTS the candidates, so
             # budget/no-budget answers stay bit-identical
-            cand_d = jnp.where(jnp.isfinite(cand_d),
-                               jnp.sum(diffk * diffk, axis=-1),
+            cand_d = jnp.where(jnp.isfinite(cand_d), S.sum_sq(diffk),
                                jnp.inf)
         else:
             # ONE local lower-bound pass for the whole batch (batched
@@ -163,15 +164,14 @@ def distributed_exact_search_batch(tree: ShardedCoconutTree,
             negm, order = jax.lax.top_k(-md, budget)         # [Q, budget]
             rows = raw[order]                                # [Q, B, L]
             diff = rows - q[:, None, :]
-            ed = jnp.sum(diff * diff, axis=-1)               # [Q, B]
+            ed = S.sum_sq(diff)                              # [Q, B]
             ed = jnp.where(jnp.isfinite(-negm), ed, jnp.inf)
             neg, idx = jax.lax.top_k(-ed, k)                 # [Q, k]
             cand_d = -neg
             cand_rows = jnp.take_along_axis(rows, idx[:, :, None],
                                             axis=1)
             diffk = cand_rows - q[:, None, :]
-            cand_d = jnp.where(jnp.isfinite(cand_d),
-                               jnp.sum(diffk * diffk, axis=-1),
+            cand_d = jnp.where(jnp.isfinite(cand_d), S.sum_sq(diffk),
                                jnp.inf)
             # certified iff the worst verified lower bound exceeds the
             # best found distance (per query, on this shard)
@@ -187,7 +187,7 @@ def distributed_exact_search_batch(tree: ShardedCoconutTree,
         rows_out = jnp.take_along_axis(r_all, idx2[:, :, None], axis=1)
         return -neg2, rows_out, jnp.all(c_all, axis=0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=tree.mesh,
         in_specs=(P(axis, None),) * 4 + (P(axis),),
         out_specs=(P(None, None), P(None, None, None), P(None,)),

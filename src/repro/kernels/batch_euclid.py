@@ -14,14 +14,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..core import summarization as S
+
 __all__ = ["batch_euclid_pallas"]
 
 
 def _kernel(q_ref, x_ref, out_ref):
     q = q_ref[...]                                  # [1, L]
     x = x_ref[...]                                  # [bn, L]
-    d = x - q
-    out_ref[...] = jnp.sum(d * d, axis=-1).astype(jnp.float32)
+    out_ref[...] = S.sum_sq(x - q).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))

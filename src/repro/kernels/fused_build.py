@@ -11,6 +11,7 @@ ends of the paper's pipeline become single-pass streaming kernels.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +47,10 @@ def _kernel(x_ref, bps_ref, paa_ref, codes_ref, keys_ref, *,
                                              "interpret"))
 def fused_build_pallas(x: jax.Array, bps: jax.Array, *, segments: int,
                        bits: int, block_n: int = 256,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Raw ``[N, L]`` -> (paa f32, codes i32, keys u32) in one pass."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     n, L = x.shape
     nb = bps.shape[0]
     nw = n_key_words(segments, bits)

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core import summarization as S
-from ..distributed.compat import shard_map
 from . import ref
 from .scan_verify import scan_verify_pallas
 
@@ -123,7 +122,7 @@ def _build_launch(mesh, axis: str, cfg: S.SummaryConfig, k: int,
         out_i = jnp.where(jnp.isfinite(out_d), out_i, -1)
         return out_d, out_i, counts
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None), P(axis, None), P(axis),

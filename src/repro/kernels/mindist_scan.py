@@ -20,6 +20,7 @@ TPU adaptation notes:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,13 +51,15 @@ def _kernel(codes_ref, qpaa_ref, lower_ref, upper_ref, out_ref, *,
                    static_argnames=("scale", "block_n", "interpret"))
 def mindist_pallas(q_paa: jax.Array, codes: jax.Array, lower: jax.Array,
                    upper: jax.Array, *, scale: float, block_n: int = 512,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """Squared mindist lower bounds: codes ``[N, w]`` -> ``[N]`` float32.
 
     ``lower``/``upper`` are the per-code region bounds (``[2**b]``, +-inf at
     the extremes replaced by large finite sentinels by the caller — the
     kernel is inf-safe but XLA:TPU prefers finite tables).
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     n, w = codes.shape
     card = lower.shape[0]
     n_pad = -(-n // block_n) * block_n

@@ -96,7 +96,7 @@ def zorder_ref(codes: jax.Array, *, w: int, b: int) -> jax.Array:
 def batch_euclid_ref(query: jax.Array, series: jax.Array) -> jax.Array:
     """query [L], series [N, L] -> squared ED [N] float32."""
     diff = series.astype(jnp.float32) - query.astype(jnp.float32)[None, :]
-    return jnp.sum(diff * diff, axis=-1)
+    return S.sum_sq(diff)
 
 
 def batch_euclid_multi_ref(queries: jax.Array,
@@ -104,7 +104,7 @@ def batch_euclid_multi_ref(queries: jax.Array,
     """queries [Q, L], series [N, L] -> squared ED [Q, N] float32."""
     diff = (series.astype(jnp.float32)[None, :, :]
             - queries.astype(jnp.float32)[:, None, :])
-    return jnp.sum(diff * diff, axis=-1)
+    return S.sum_sq(diff)
 
 
 # rows per blocked-ED step: the naive [Q, N, L] difference tensor is

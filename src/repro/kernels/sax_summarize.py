@@ -13,6 +13,7 @@ table (``code = #{breakpoints <= paa}``) — a dense VPU reduction over the
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +38,10 @@ def _kernel(x_ref, bps_ref, paa_ref, codes_ref, *, segments: int):
 @functools.partial(jax.jit, static_argnames=("segments", "block_n",
                                              "interpret"))
 def sax_summarize_pallas(x: jax.Array, bps: jax.Array, *, segments: int,
-                         block_n: int = 256, interpret: bool = True):
+                         block_n: int = 256, interpret: Optional[bool] = None):
     """Raw series ``[N, L]`` -> (paa ``[N, w]`` f32, codes ``[N, w]`` int32)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     n, L = x.shape
     nb = bps.shape[0]
     n_pad = -(-n // block_n) * block_n

@@ -16,8 +16,18 @@ TPU adaptation notes:
   * The query tiles (raw + PAA), the region-bound tables, and the
     running top-k accumulators use constant index maps, so they stay
     VMEM-resident across the entire N-grid.
-  * The per-code region lookup reuses the one-hot compare+select+reduce
-    trick from ``mindist_batch`` (gathers are hostile to the VPU).
+  * Codes stream transposed (``[w, block_n]``) into the column-at-a-time
+    bound of ``mindist_batch.bound_tile``, so the bound never builds a
+    ``[block_n, w, 2**b]`` one-hot (gathers are hostile to the VPU, and
+    that cube alone is 4 MiB of f32 at the paper's widths).
+  * The verification loops over chunks of ``_query_chunk`` queries, so
+    only one ``[Qc, block_n, L]`` difference tile is live at a time and
+    Q=64 at L=256 fits the 16 MiB scoped VMEM.  Each chunk's distances go
+    to a ``[Q, block_n]`` VMEM scratch; the wrapper pads the query batch
+    to whole chunks (padded queries have bound -inf, so nothing is live
+    for them).  Every chunk sums its squares with
+    ``summarization.sum_sq``, in the fixed order the eager chain uses,
+    so a row's distance has the same bits on both paths.
   * The top-k merge is gather-free selection: k unrolled rounds of
     min/argmin + one-hot masking over the ``[Q, k + block_n]``
     concatenation — no sort network, no dynamic indexing.
@@ -32,13 +42,29 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..core import summarization as S
+from .mindist_batch import bound_tile
 
 __all__ = ["scan_verify_pallas"]
 
+# VMEM bytes of one query chunk's [Qc, block_n, L] difference tile
+_ED_TILE_BYTES = 1 << 21
+
+
+def _query_chunk(nq: int, block_n: int, L: int) -> int:
+    """Queries per verification chunk: as many as keep the difference
+    tile under ``_ED_TILE_BYTES``, and a multiple of the 8-row sublane
+    tile whenever the batch needs more than one chunk."""
+    qc = max(1, _ED_TILE_BYTES // (block_n * L * 4))
+    return nq if nq <= qc else max(8, qc // 8 * 8)
+
 
 def _kernel(codes_ref, raw_ref, q_ref, qpaa_ref, lower_ref, upper_ref,
-            bound_ref, dead_ref, outd_ref, outi_ref, cnt_ref, uni_ref, *,
-            card: int, scale: float, k: int, n: int, block_n: int):
+            bound_ref, dead_ref, outd_ref, outi_ref, cnt_ref, uni_ref,
+            ed_ref, *, w: int, scale: float, k: int, n: int, block_n: int,
+            qc: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -48,20 +74,11 @@ def _kernel(codes_ref, raw_ref, q_ref, qpaa_ref, lower_ref, upper_ref,
         cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
         uni_ref[...] = jnp.zeros(uni_ref.shape, jnp.int32)
 
-    codes = codes_ref[...].astype(jnp.int32)          # [bn, w]
-    q_paa = qpaa_ref[...]                             # [Q, w]
-    bn, w = codes.shape
-    # one-hot region-bound lookup: VPU compare+select+reduce, no gather
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, w, card), 2)
-    onehot = codes[:, :, None] == iota
-    lb = jnp.sum(jnp.where(onehot, lower_ref[...][0][None, None, :], 0.0),
-                 axis=-1)
-    ub = jnp.sum(jnp.where(onehot, upper_ref[...][0][None, None, :], 0.0),
-                 axis=-1)
-    below = jnp.maximum(lb[None, :, :] - q_paa[:, None, :], 0.0)
-    above = jnp.maximum(q_paa[:, None, :] - ub[None, :, :], 0.0)
-    d = below + above
-    md = scale * jnp.sum(d * d, axis=-1)              # [Q, bn]
+    codes = codes_ref[...]                            # [w, bn] int32
+    bn = codes.shape[1]
+    md = bound_tile(lambda j: codes[j:j + 1, :], qpaa_ref[...],
+                    lower_ref[...], upper_ref[...], w=w,
+                    scale=scale)                      # [Q, bn]
 
     rowid = i * block_n + jax.lax.broadcasted_iota(jnp.int32, (bn,), 0)
     valid = (rowid < n) & (dead_ref[...][0] == 0)
@@ -74,10 +91,16 @@ def _kernel(codes_ref, raw_ref, q_ref, qpaa_ref, lower_ref, upper_ref,
 
     # early-abandoning verify: rows the bound pruned contribute inf only
     x = raw_ref[...]                                  # [bn, L]
-    qq = q_ref[...]                                   # [Q, L]
-    diff = x[None, :, :] - qq[:, None, :]
-    ed = jnp.sum(diff * diff, axis=-1)                # [Q, bn]
-    ed = jnp.where(live, ed, jnp.inf)
+    nq = q_ref.shape[0]
+
+    def chunk(c, carry):
+        c0 = pl.multiple_of(c * qc, qc)
+        qq = q_ref[pl.ds(c0, qc), :]                  # [Qc, L]
+        ed_ref[pl.ds(c0, qc), :] = S.sum_sq(x[None, :, :] - qq[:, None, :])
+        return carry
+
+    jax.lax.fori_loop(0, nq // qc, chunk, 0)
+    ed = jnp.where(live, ed_ref[...], jnp.inf)        # [Q, bn]
 
     # merge the tile into the running top-k (gather-free selection)
     cat_d = jnp.concatenate([outd_ref[...], ed], axis=1)   # [Q, k+bn]
@@ -119,25 +142,31 @@ def scan_verify_pallas(queries: jax.Array, q_paas: jax.Array,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n, w = codes.shape
-    nq, L = queries.shape
+    nq0, L = queries.shape
+    qc = _query_chunk(nq0, block_n, L)
+    nq = -(-nq0 // qc) * qc
+    if nq != nq0:
+        queries = jnp.pad(queries, ((0, nq - nq0), (0, 0)))
+        q_paas = jnp.pad(q_paas, ((0, nq - nq0), (0, 0)))
+        bound = jnp.pad(bound, (0, nq - nq0), constant_values=-jnp.inf)
     card = lower.shape[0]
     n_pad = -(-n // block_n) * block_n
-    codes_p = jnp.pad(codes.astype(jnp.int32), ((0, n_pad - n), (0, 0)))
+    codes_t = jnp.pad(codes.astype(jnp.int32), ((0, n_pad - n), (0, 0))).T
     raw_p = jnp.pad(raw.astype(jnp.float32), ((0, n_pad - n), (0, 0)))
     dead_p = jnp.pad(dead.astype(jnp.int32), (0, n_pad - n),
                      constant_values=1)
     grid = (n_pad // block_n,)
     out_d, out_i, cnt, uni = pl.pallas_call(
-        functools.partial(_kernel, card=card, scale=float(scale), k=k,
-                          n=n, block_n=block_n),
+        functools.partial(_kernel, w=w, scale=float(scale), k=k,
+                          n=n, block_n=block_n, qc=qc),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n, w), lambda i: (i, 0)),
+            pl.BlockSpec((w, block_n), lambda i: (0, i)),
             pl.BlockSpec((block_n, L), lambda i: (i, 0)),
             pl.BlockSpec((nq, L), lambda i: (0, 0)),
             pl.BlockSpec((nq, w), lambda i: (0, 0)),
-            pl.BlockSpec((1, card), lambda i: (0, 0)),
-            pl.BlockSpec((1, card), lambda i: (0, 0)),
+            pl.BlockSpec((card, 1), lambda i: (0, 0)),
+            pl.BlockSpec((card, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, nq), lambda i: (0, 0)),
             pl.BlockSpec((1, block_n), lambda i: (0, i)),
         ],
@@ -153,10 +182,11 @@ def scan_verify_pallas(queries: jax.Array, q_paas: jax.Array,
             jax.ShapeDtypeStruct((1, nq), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ),
+        scratch_shapes=[pltpu.VMEM((nq, block_n), jnp.float32)],
         interpret=interpret,
-    )(codes_p, raw_p, queries.astype(jnp.float32),
+    )(codes_t, raw_p, queries.astype(jnp.float32),
       q_paas.astype(jnp.float32),
-      lower[None, :].astype(jnp.float32),
-      upper[None, :].astype(jnp.float32),
+      lower[:, None].astype(jnp.float32),
+      upper[:, None].astype(jnp.float32),
       bound[None, :].astype(jnp.float32), dead_p[None, :])
-    return out_d, out_i, cnt[0], uni[0, 0]
+    return out_d[:nq0], out_i[:nq0], cnt[0, :nq0], uni[0, 0]

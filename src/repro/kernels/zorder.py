@@ -10,6 +10,7 @@ HBM round trip: raw series in, sortable keys out.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +36,10 @@ def _kernel(codes_ref, out_ref, *, w: int, b: int, n_words: int):
 @functools.partial(jax.jit, static_argnames=("w", "b", "block_n",
                                              "interpret"))
 def zorder_pallas(codes: jax.Array, *, w: int, b: int, block_n: int = 1024,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """SAX codes ``[N, w]`` -> z-order keys ``[N, n_words]`` uint32."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     n = codes.shape[0]
     nw = n_key_words(w, b)
     n_pad = -(-n // block_n) * block_n

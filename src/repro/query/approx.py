@@ -61,7 +61,7 @@ import numpy as np
 from ..core import summarization as S
 from ..obs import record_search, span as _span
 from .executor import (_leaves_per_group, _scan_buffer, _scan_leaf_group,
-                       _seed_sorted)
+                       _seed_sorted, device_queries, query_paas)
 from .merger import KnnPool, SearchStats
 from .partition import Partition
 from .planner import ScanPlan, build_plan
@@ -121,9 +121,7 @@ def _drain(plan: ScanPlan, queries_np: np.ndarray, *, k: int,
            chunk: int, io, mindist_fn, plan_ms: float = 0.0
            ) -> Iterator[Tuple[np.ndarray, np.ndarray, SearchStats]]:
     """The budgeted frontier drain (generator of improving snapshots)."""
-    import jax.numpy as jnp
-    queries_j = jnp.asarray(queries_np)
-    q_paas_j = jnp.asarray(plan.q_paas)
+    queries_j, q_paas_j = device_queries(queries_np, plan.q_paas)
     nq = queries_np.shape[0]
     pool = KnnPool(nq, k, ext=bsf)
     stats = SearchStats(exact=False, queries=nq)
@@ -313,10 +311,9 @@ def approx_knn(partitions: Sequence[Partition], queries,
     query.  ``budget=None`` drains every surviving leaf: the answer is
     bit-identical to the exact pipeline and ``gap == 0``.
     """
-    import jax.numpy as jnp
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
     t0 = time.perf_counter()
-    q_paas = np.asarray(S.paa(jnp.asarray(queries_np), cfg.segments))
+    q_paas = query_paas(queries_np, cfg.segments)
     plan = build_plan(partitions, q_paas, ts_min=ts_min,
                       temporal_prune=temporal_prune, io=io)
     plan_ms = (time.perf_counter() - t0) * 1e3
@@ -348,10 +345,9 @@ def progressive_knn(partitions: Sequence[Partition], queries,
     may stop early (e.g. once ``stats.gap`` is small enough) — the
     generator abandons the rest of the scan on ``close()``.
     """
-    import jax.numpy as jnp
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
     t0 = time.perf_counter()
-    q_paas = np.asarray(S.paa(jnp.asarray(queries_np), cfg.segments))
+    q_paas = query_paas(queries_np, cfg.segments)
     plan = build_plan(partitions, q_paas, ts_min=ts_min,
                       temporal_prune=temporal_prune, io=io)
     plan_ms = (time.perf_counter() - t0) * 1e3

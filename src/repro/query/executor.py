@@ -31,7 +31,35 @@ from .merger import KnnPool, SearchStats
 from .partition import Partition
 from .planner import ScanEntry, ScanPlan, build_plan
 
-__all__ = ["execute", "exact_knn", "buffer_topk"]
+__all__ = ["execute", "exact_knn", "buffer_topk", "device_queries",
+           "query_paas"]
+
+
+def _pad_batch(x: np.ndarray) -> np.ndarray:
+    """``x`` with its rows padded to the next power of two by repeating
+    the last one.  Programs are compiled per shape, and a shard or run is
+    probed with whatever subset of the batch its bounds let through, so
+    unpadded every subset size would compile programs of its own."""
+    n = x.shape[0]
+    return np.pad(x, ((0, (1 << (n - 1).bit_length()) - n), (0, 0)),
+                  mode="edge")
+
+
+def device_queries(queries: np.ndarray, q_paas: np.ndarray):
+    """The query batch and its PAAs as the device programs see them,
+    padded by :func:`_pad_batch`.  The scan keeps its per-query state for
+    the real rows only (``KnnPool``) and drops the padded rows of every
+    result."""
+    import jax.numpy as jnp
+    return jnp.asarray(_pad_batch(queries)), jnp.asarray(_pad_batch(q_paas))
+
+
+def query_paas(queries: np.ndarray, segments: int) -> np.ndarray:
+    """Host PAAs ``[Q, w]`` of a query batch, computed on the padded
+    batch (:func:`_pad_batch`)."""
+    import jax.numpy as jnp
+    paas = S.paa(jnp.asarray(_pad_batch(queries)), segments)
+    return np.asarray(paas)[:queries.shape[0]]
 
 
 def buffer_topk(queries_j, rows: np.ndarray, offs: np.ndarray, k: int,
@@ -70,7 +98,7 @@ def _scan_buffer(entry: ScanEntry, queries_j, k: int,
     if len(rows) == 0:
         return
     new_d, new_off = buffer_topk(queries_j, rows, offs, k, io=io)
-    pool.update_batch(new_d, new_off)
+    pool.update_batch(new_d[:pool.nq], new_off[:pool.nq])
     stats.buffer_rows += len(rows)
     stats.candidates_per_query += len(rows)
 
@@ -84,7 +112,7 @@ def _seed_sorted(entry: ScanEntry, queries_j, q_paas_j,
     drain so seed distance bits are identical by construction."""
     import jax.numpy as jnp
     part = entry.partition
-    nq = queries_j.shape[0]
+    nq = pool.nq
     alive = None
     if entry.ts_min is not None:
         ts = part.timestamps()
@@ -94,12 +122,11 @@ def _seed_sorted(entry: ScanEntry, queries_j, q_paas_j,
     idx0 = part.seed_window(queries_j, radius_leaves=radius_leaves, io=io,
                             q_paas=q_paas_j)
     rows0 = part.series_rows(idx0.reshape(-1), io=io)
-    # canonical bits: seed distances use the eager kernel's reduction
-    # (sum over the contiguous last axis) so returned values never depend
-    # on partitioning — one gather + one batched op for the whole pool
+    # canonical bits: seed distances add in the verifier's fixed order
+    # (``S.sum_sq``) so returned values never depend on partitioning —
+    # one gather + one batched op for the whole pool
     rows0 = jnp.asarray(rows0).reshape(idx0.shape + (-1,))    # [Q, C, L]
-    diff0 = rows0 - queries_j[:, None, :]
-    d0 = np.asarray(jnp.sum(diff0 * diff0, axis=-1), np.float32)
+    d0 = np.asarray(S.sq_dist(rows0, queries_j[:, None, :]), np.float32)
     if alive is not None:
         d0 = np.where(alive[idx0], d0, np.inf)
         offs0 = np.where(alive[idx0], offs_all[idx0], -1)
@@ -107,7 +134,7 @@ def _seed_sorted(entry: ScanEntry, queries_j, q_paas_j,
         offs0 = offs_all[idx0]
     for qi in range(nq):
         pool.update(qi, d0[qi], offs0[qi])
-    return alive, offs_all, idx0
+    return alive, offs_all, idx0[:nq]
 
 
 def _leaves_per_group(chunk: int, nq: int, leaf: int) -> int:
@@ -116,6 +143,31 @@ def _leaves_per_group(chunk: int, nq: int, leaf: int) -> int:
     thrashes host memory)."""
     eff_chunk = min(chunk, max(64, 32768 // nq))
     return max(1, eff_chunk // leaf)
+
+
+def _verify_rows(n: int) -> int:
+    """Row count a verification block is padded to: the next power of
+    two, at least 64.  Which rows survive the bound is data-dependent, so
+    unpadded blocks would compile the verification anew for nearly every
+    leaf group; padded, a scan reuses a handful of programs.  Each row's
+    distance is its own reduction, so padding never changes its bits."""
+    return max(64, 1 << (int(n) - 1).bit_length())
+
+
+def _bound_rows(part: Partition, idx: np.ndarray, io, *, packed: bool):
+    """Code rows (packed or full width) for ``idx``, padded with copies
+    of the last row to :func:`_verify_rows` rows.  Reads are charged as
+    for ``idx`` alone: device and tier-cached partitions are read (and
+    charged) leaf by leaf, and the padding only repeats a row of a leaf
+    already read; a bare mmap charges per row, so there the block is
+    padded after the read."""
+    npad = _verify_rows(len(idx)) - len(idx)
+    if part.backend == "device" or part.tiers is not None:
+        idx, npad = np.pad(idx, (0, npad), mode="edge"), 0
+    blk = (part.codes_rows_packed if packed else part.codes_rows)(idx, io=io)
+    if npad:
+        blk = np.pad(np.asarray(blk), ((0, npad), (0, 0)), mode="edge")
+    return blk
 
 
 def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
@@ -131,7 +183,7 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     of the ``max_bytes`` budget)."""
     import jax.numpy as jnp
     part = entry.partition
-    nq = queries_j.shape[0]
+    nq = pool.nq
     leaf = part.leaf_size
     row_idx = (grp[:, None] * leaf
                + np.arange(leaf)[None, :]).reshape(-1)
@@ -157,17 +209,22 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     # and device-promoted hot leaves skip the host->device copy too.
     # Both bound paths compute identical bits, so answers never depend
     # on which one ran.
+    # the bound runs over the rows padded like a verification block
+    # (edge rows, dropped below), so groups cut short by a run's last
+    # leaf reuse the programs of whole ones
+    nr = len(row_idx)
     if (part.is_packed
             and getattr(mindist_fn, "_coconut_default_mindist", False)):
         from ..kernels import ops
-        packed_blk = part.codes_rows_packed(row_idx, io=io)
-        md = np.asarray(ops.mindist_batch_packed(
-            q_paas_j, jnp.asarray(packed_blk), part.cfg))     # [Q, B]
+        packed_blk = _bound_rows(part, row_idx, io, packed=True)
+        md = ops.mindist_batch_packed(q_paas_j, jnp.asarray(packed_blk),
+                                      part.cfg)
     else:
-        codes_blk = part.codes_rows(row_idx, io=io)
+        codes_blk = _bound_rows(part, row_idx, io, packed=False)
         if part.backend != "device":
             codes_blk = jnp.asarray(codes_blk)
-        md = np.asarray(mindist_fn(q_paas_j, codes_blk))      # [Q, B]
+        md = mindist_fn(q_paas_j, codes_blk)
+    md = np.asarray(md)[:nq, :nr]                             # [Q, B]
     live = md < pool.bound()[:, None]
     if alive is not None:
         live &= alive[row_idx][None, :]
@@ -179,11 +236,19 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     mask = live[:, keep]
     t0 = time.perf_counter()
     with _span("verify", rows=len(block)) as vsp:
-        rows = part.series_rows(block, io=io)
-        if part.backend == "device" and io is not None:
-            io.seq_read(len(block))
-        dd = np.asarray(S.euclidean_sq_batch(queries_j,
-                                             jnp.asarray(rows)))   # [Q, B]
+        nb = len(block)
+        pad = _verify_rows(nb) - nb
+        if part.backend == "device":
+            # pad the device gather itself (edge rows, dropped below)
+            rows = part.series_rows(np.pad(block, (0, pad), mode="edge"),
+                                    io=io)
+            if io is not None:
+                io.seq_read(nb)
+        else:
+            rows = np.pad(np.asarray(part.series_rows(block, io=io)),
+                          ((0, pad), (0, 0)))
+        dd = np.asarray(S.euclidean_sq_batch(
+            queries_j, jnp.asarray(rows)))[:nq, :nb]           # [Q, B]
         nbytes += len(block) * part.cfg.series_len * 4
         stats.candidates += len(block)
         union_mark[block // leaf] = True
@@ -208,7 +273,7 @@ def _scan_sorted(entry: ScanEntry, queries_j, q_paas_j, k: int,
     """Seed + leaf-skip scan + verify one sorted partition.  Returns the
     number of live (query, row) pairs the lower bound could not prune."""
     part = entry.partition
-    nq = queries_j.shape[0]
+    nq = pool.nq
     leaf = part.leaf_size
     # the fused kernel streams the whole leaf group's raw rows (that is
     # the fusion); on mmap partitions that would fetch pruned rows' raw
@@ -276,9 +341,10 @@ def _verify_fused(entry: ScanEntry, queries_j, q_paas_j, codes_blk,
     import jax.numpy as jnp
     from ..kernels import ops
     part = entry.partition
-    nq = queries_j.shape[0]
+    nq = pool.nq
     rows = part.series_rows(row_idx, io=io)
     bound = pool.bound()
+    bound = np.pad(bound, (0, queries_j.shape[0] - nq), mode="edge")
     if alive is not None:
         dead = ~alive[row_idx]
     else:
@@ -288,9 +354,9 @@ def _verify_fused(entry: ScanEntry, queries_j, q_paas_j, codes_blk,
         jnp.asarray(bound), part.cfg, k=min(k, len(row_idx)),
         mode=scan_mode,
         dead=None if dead is None else jnp.asarray(dead))
-    d = np.asarray(d, np.float32)
-    li = np.asarray(li)
-    counts = np.asarray(counts)
+    d = np.asarray(d, np.float32)[:nq]
+    li = np.asarray(li)[:nq]
+    counts = np.asarray(counts)[:nq]
     live = 0
     for qi in range(nq):
         stats.candidates_per_query[qi] += int(counts[qi])
@@ -330,13 +396,11 @@ def execute(plan: ScanPlan, queries, *, k: int = 1,
     the sharded fan-out) and this executor IS its threaded fallback, so
     a mesh request that reaches here runs the canonical eager chain.
     """
-    import jax.numpy as jnp
     if scan_mode == "mesh":
         scan_mode = None
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
     nq = queries_np.shape[0]
-    queries_j = jnp.asarray(queries_np)
-    q_paas_j = jnp.asarray(plan.q_paas)
+    queries_j, q_paas_j = device_queries(queries_np, plan.q_paas)
     pool = KnnPool(nq, k, ext=bsf)
     stats = SearchStats(exact=True, queries=nq)
     stats.candidates_per_query = np.zeros(nq, np.int64)
@@ -396,10 +460,9 @@ def exact_knn(partitions: Sequence[Partition], queries,
               ) -> Tuple[np.ndarray, np.ndarray, SearchStats]:
     """Plan + execute in one call — the pipeline every exact-search entry
     point (tree, snapshot, sharded shard, mmap segment) delegates to."""
-    import jax.numpy as jnp
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
     t0 = time.perf_counter()
-    q_paas = np.asarray(S.paa(jnp.asarray(queries_np), cfg.segments))
+    q_paas = query_paas(queries_np, cfg.segments)
     plan = build_plan(partitions, q_paas, ts_min=ts_min,
                       temporal_prune=temporal_prune, io=io)
     plan_ms = (time.perf_counter() - t0) * 1e3

@@ -164,6 +164,10 @@ class KnnPool:
         self.ext = (np.full(nq, np.inf, np.float32) if ext is None
                     else np.asarray(ext, np.float32))
 
+    @property
+    def nq(self) -> int:
+        return self.best_d.shape[0]
+
     def bound(self) -> np.ndarray:
         """[Q] pruning bound: min(k-th best, external bsf)."""
         return np.minimum(self.best_d[:, -1], self.ext)

@@ -28,19 +28,18 @@ insert) are scanned host-side by the caller first, and their k-th
 distances seed the launch ``bound`` — the same bsf-chaining the
 threaded fan-out applies across shards, applied across the whole mesh.
 
-Bit-parity protocol: the repo's canonical distance bits are the EAGER
-kernel chain's (see ``query/executor.py`` — seeds and verification both
-dispatch ``sub -> mul -> sum`` as separate eager ops precisely so the
-bits never depend on partitioning).  A fully fused jit program is
-allowed to reassociate that reduction, so the launch's on-device
-distances are treated as *selection* scores only: after the launch
-picks each query's top-k rows, :meth:`MeshScanEngine.launch`
-re-verifies exactly those rows with the same eager op sequence (shape
-[n_sel, L]; elementwise ops are exact and the standalone reduction is
-shape-independent, so the values are bit-identical to what the threaded
-executor returns for the same rows).  Selection itself can only differ
-from the threaded path when two rows' true distances sit within one
-ulp — the same measure-zero tie class both paths already carry.
+Bit-parity protocol: the repo's canonical distance bits are those of
+``summarization.sum_sq``, which adds a row's squares in one fixed
+pairwise order of elementwise ops, so the bits never depend on the
+shape a row was verified in.  The launch adds in that order too, but
+its on-device distances are still treated as *selection* scores only:
+after the launch picks each query's top-k rows,
+:meth:`MeshScanEngine.launch` re-verifies exactly those rows with the
+eager verifier (``summarization.sq_dist``), so the values are the ones
+the threaded executor returns for the same rows whatever the kernel's
+compiler did.  Selection itself can only differ from the threaded path
+when two rows' true distances sit within one ulp — the same
+measure-zero tie class both paths already carry.
 """
 from __future__ import annotations
 
@@ -261,8 +260,7 @@ class MeshScanEngine:
             pos = np.searchsorted(pinned.ids_sorted, ids64[valid])
             slot = pinned.id_order[pos]
             rows = jnp.asarray(pinned.host_raw[slot])
-            diff = rows - jnp.asarray(queries[qi])
-            d[valid] = np.asarray(jnp.sum(diff * diff, axis=-1),
+            d[valid] = np.asarray(S.sq_dist(rows, jnp.asarray(queries[qi])),
                                   np.float32)
             # keep each query's pool sorted after the re-verification
             # (stable: sub-ulp rank flips keep the launch's order)

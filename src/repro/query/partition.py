@@ -255,7 +255,8 @@ class Partition:
                 device = False
                 parts.append(blk[local])
             else:
-                parts.append(blk[local])       # jnp fancy index
+                from ..core.tree import take_rows
+                parts.append(take_rows(blk, local))
         if len(parts) == 1:
             return parts[0]
         if device:
@@ -270,7 +271,8 @@ class Partition:
         segments)."""
         if self.kind == "tree":
             import jax.numpy as jnp
-            return self.source.codes[jnp.asarray(idx)]
+            from ..core.tree import take_rows
+            return take_rows(self.source.codes, jnp.asarray(idx))
         if self.kind == "segment" and self.tiers is not None:
             blk = self._gather_rows("codes", idx, io)
             if self.is_packed:

@@ -311,3 +311,59 @@ def test_batch_euclid_default_resolves_by_backend(monkeypatch):
     want = ref.batch_euclid_ref(x[0], x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_scan_verify_query_chunks_carry_eager_bits():
+    """Q=11 at the paper's L=256: the kernel verifies in chunks of 8
+    queries (the batch padded to 16) and every distance it returns has
+    the eager verifier's bits."""
+    cfg = S.SummaryConfig(series_len=256, segments=16, bits=8)
+    x = _data(600, 256)
+    _paa, codes = S.summarize(x, cfg)
+    queries = _data(11, 256, seed=9)
+    lower = jnp.nan_to_num(S.region_bounds(8)[0], neginf=-1e30)
+    upper = jnp.nan_to_num(S.region_bounds(8)[1], posinf=1e30)
+    d_k, i_k, c_k, _u = scan_verify_pallas(
+        queries, S.paa(queries, 16), codes.astype(jnp.int32), x, lower,
+        upper, jnp.full(11, jnp.inf, jnp.float32), jnp.zeros(600, jnp.int32),
+        scale=16.0, k=4, block_n=256, interpret=True)
+    ed = np.asarray(S.euclidean_sq_batch(queries, x))
+    want = np.argsort(ed, axis=1, kind="stable")[:, :4]
+    assert np.array_equal(np.asarray(i_k), want)
+    assert np.array_equal(np.asarray(d_k),
+                          np.take_along_axis(ed, want, axis=1))
+    assert np.array_equal(np.asarray(c_k), np.full(11, 600))
+
+
+def _pairwise_sum_np(sq: np.ndarray) -> np.ndarray:
+    """The documented order of adds, in numpy float32: zero-pad the last
+    axis to a power of two, then element i meets element i + h as h
+    halves down to 1."""
+    p = 1 << (sq.shape[-1] - 1).bit_length()
+    s = np.zeros(sq.shape[:-1] + (p,), np.float32)
+    s[..., :sq.shape[-1]] = sq
+    while s.shape[-1] > 1:
+        h = s.shape[-1] // 2
+        s = s[..., :h] + s[..., h:]
+    return s[..., 0]
+
+
+@pytest.mark.parametrize("L", [32, 100, 256])
+def test_distance_bits_fixed_by_order(L):
+    """A row's squared distance is the documented pairwise sum, whatever
+    the batch or block it is computed in: the same bits alone, in a
+    batch of 16, over a row block, and through the ``batch_euclid``
+    kernel."""
+    x = np.asarray(_data(300, L))
+    q = np.asarray(_data(16, L, seed=5))
+    diff = x[None, :, :] - q[:, None, :]
+    want = _pairwise_sum_np(diff * diff)
+    full = np.asarray(S.euclidean_sq_batch(q, x))
+    assert np.array_equal(full, want)
+    for qi in (0, 7, 15):
+        one = np.asarray(S.euclidean_sq_batch(q[qi:qi + 1], x[40:77]))
+        assert np.array_equal(one[0], want[qi, 40:77])
+        assert np.array_equal(np.asarray(S.euclidean_sq(q[qi], x)),
+                              want[qi])
+        kern = batch_euclid_pallas(q[qi], x, block_n=128, interpret=True)
+        assert np.array_equal(np.asarray(kern), want[qi])

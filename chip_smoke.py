@@ -253,8 +253,7 @@ def streaming(sz: Sizes, seed: int, workdir: str):
     from repro.configs.coconut_paper import INDEX, LEAF_SIZE
     from repro.core.lsm import CoconutLSM
     from repro.data.series import query_workload
-    from repro.obs import get_registry
-    from repro.obs.profile import disable_profiling, enable_profiling
+    from repro.kernels import ops
     from repro.storage.store import SegmentStore
     from repro.storage.tiers import TieredLeafStore
 
@@ -283,9 +282,15 @@ def streaming(sz: Sizes, seed: int, workdir: str):
 
     cache = TieredLeafStore(256 << 20)
     lsm = CoconutLSM.open(workdir, tiers=cache)
-    hist = get_registry().histogram("kernel.mindist_batch_packed_ms")
-    before = hist.count
-    enable_profiling("wall")
+    packed = ops.mindist_batch_packed
+    launches = 0
+
+    def counted(*args, **kw):
+        nonlocal launches
+        launches += 1
+        return packed(*args, **kw)
+
+    ops.mindist_batch_packed = counted      # counts the packed-path launches
     try:
         d2, ids2, _ = lsm.search_exact_batch(queries, k=sz.k,
                                              window=sz.window)
@@ -298,8 +303,7 @@ def streaming(sz: Sizes, seed: int, workdir: str):
             check_same(f"reopened: query {qi} alone", dq[0], iq[0],
                        d[qi], ids[qi])
     finally:
-        disable_profiling()
-    launches = hist.count - before
+        ops.mindist_batch_packed = packed
     check_same("reopened index", d2, ids2, d, ids)
     print(f"  reopened: answers identical; packed-path launches "
           f"{launches}, cache.promotions {cache.promotions}", flush=True)

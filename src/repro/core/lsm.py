@@ -398,61 +398,66 @@ class CoconutLSM:
         batch, as produced by ``summarization.summarize`` — the router
         computes them for routing and threads them here so the flush-time
         run build does not summarize the rows a second time.
+
+        Traced as one ``insert`` span over the WAL append and any inline
+        flush, merge and commit.
         """
-        self._check_open()
-        if self._compactor is not None:
-            self._compactor.check()
-        raw = np.asarray(raw, np.float32)
-        n = raw.shape[0]
-        with self._wal_lock:           # fixes WAL record order == FIFO order
-            with self._cv:
-                if timestamps is None:
-                    timestamps = np.arange(self.clock, self.clock + n,
-                                           dtype=np.int64)
-                else:
-                    timestamps = np.asarray(timestamps, np.int64)
-                # monotone: out-of-order caller timestamps never regress
-                # the clock (a regressing clock would shift window cuts
-                # and break shard-count invariance)
-                self.clock = max(self.clock, int(timestamps.max()) + 1)
-                self.data_epoch += 1
-                start_row = self._rows_inserted
-                self._rows_inserted += n
-                if ids is None:
-                    ids = np.arange(start_row, start_row + n,
-                                    dtype=np.int64)
-                else:
-                    ids = np.asarray(ids, np.int64)
-                self._buf_raw.append(raw)
-                self._buf_ts.append(timestamps)
-                self._buf_ids.append(ids)
-                self._buf_fence.append(key_fence)
-                self._buf_sum.append(summaries)
-                self._buf_count += n
-                self.ingest.add("rows_ingested", n)
-                self.ingest.set_gauge("ingest_lag_rows", self._lag_locked())
-                if self.concurrent:
-                    self._cv.notify_all()
-            # the disk write + fsync happens OUTSIDE the engine lock, so
-            # snapshots and the compactor never wait on an insert's sync.
-            # (If a flush commits these rows before the record lands, the
-            # manifest's wal_start simply skips it at replay.)
-            if self.wal is not None:
-                self.wal.append(raw, timestamps, start_row, ids=ids)
-        if self.concurrent:
-            with self._cv:             # bounded-debt backpressure
-                throttled = False
-                while (self._debt_locked() > self.max_debt
-                       and self._compactor.error is None
-                       and self._compactor.alive):
-                    if not throttled:
-                        self.ingest.add("backpressure_waits")
-                        throttled = True
-                    self._cv.wait(timeout=0.5)
-            self._compactor.check()
-        else:
-            while self._buf_count >= self.buffer_capacity:
-                self._flush()
+        with _span("insert", rows=len(raw)):
+            self._check_open()
+            if self._compactor is not None:
+                self._compactor.check()
+            raw = np.asarray(raw, np.float32)
+            n = raw.shape[0]
+            with self._wal_lock:       # fixes WAL record order == FIFO order
+                with self._cv:
+                    if timestamps is None:
+                        timestamps = np.arange(self.clock, self.clock + n,
+                                               dtype=np.int64)
+                    else:
+                        timestamps = np.asarray(timestamps, np.int64)
+                    # monotone: out-of-order caller timestamps never regress
+                    # the clock (a regressing clock would shift window cuts
+                    # and break shard-count invariance)
+                    self.clock = max(self.clock, int(timestamps.max()) + 1)
+                    self.data_epoch += 1
+                    start_row = self._rows_inserted
+                    self._rows_inserted += n
+                    if ids is None:
+                        ids = np.arange(start_row, start_row + n,
+                                        dtype=np.int64)
+                    else:
+                        ids = np.asarray(ids, np.int64)
+                    self._buf_raw.append(raw)
+                    self._buf_ts.append(timestamps)
+                    self._buf_ids.append(ids)
+                    self._buf_fence.append(key_fence)
+                    self._buf_sum.append(summaries)
+                    self._buf_count += n
+                    self.ingest.add("rows_ingested", n)
+                    self.ingest.set_gauge("ingest_lag_rows",
+                                          self._lag_locked())
+                    if self.concurrent:
+                        self._cv.notify_all()
+                # the disk write + fsync happens OUTSIDE the engine lock, so
+                # snapshots and the compactor never wait on an insert's sync.
+                # (If a flush commits these rows before the record lands, the
+                # manifest's wal_start simply skips it at replay.)
+                if self.wal is not None:
+                    self.wal.append(raw, timestamps, start_row, ids=ids)
+            if self.concurrent:
+                with self._cv:             # bounded-debt backpressure
+                    throttled = False
+                    while (self._debt_locked() > self.max_debt
+                           and self._compactor.error is None
+                           and self._compactor.alive):
+                        if not throttled:
+                            self.ingest.add("backpressure_waits")
+                            throttled = True
+                        self._cv.wait(timeout=0.5)
+                self._compactor.check()
+            else:
+                while self._buf_count >= self.buffer_capacity:
+                    self._flush()
 
     def flush(self) -> None:
         """Force-flush the in-memory buffer (e.g. before a snapshot).
@@ -575,15 +580,14 @@ class CoconutLSM:
                    t_min=int(head_ts.min()), t_max=int(head_ts.max()))
 
     def _merge_trees(self, a: Run, b: Run) -> T.CoconutTree:
-        """Timed wrapper over ``tree.merge_trees`` shared by the inline
-        (``_flush``) and background (``_bg_step``) merge sites."""
-        t0 = time.perf_counter()
+        """Traced wrapper over ``tree.merge_trees`` shared by the inline
+        (``_flush``) and background (``_bg_step``) merge sites.  The span
+        covers the merge's dispatch; its device work lands later, in
+        whatever first reads the merged run (``segment.fetch`` at the
+        commit)."""
         with _span("compact.merge", rows=a.n + b.n,
                    level_a=a.level, level_b=b.level):
-            merged = T.merge_trees(a.tree, b.tree, io=self.io)
-        get_registry().histogram("compact.merge_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
-        return merged
+            return T.merge_trees(a.tree, b.tree, io=self.io)
 
     def _publish_run(self, entry, run: Run) -> None:
         """Atomically swap the flushed head out of the buffer view and the

@@ -806,7 +806,8 @@ class ShardedCoconutLSM:
             ids = np.concatenate(buf_ids, axis=0)
             with _span("buffer", rows=len(rows)):
                 best_d, best_off = buffer_topk(
-                    jnp.asarray(queries), rows, ids, k, io=self.io)
+                    jnp.asarray(queries), rows, ids, k, io=self.io,
+                    stats=stats)
             stats.buffer_rows = len(rows)
             info["buffer_rows"] = len(rows)
             info["partitions_touched"] += sum(

@@ -52,7 +52,8 @@ from typing import Iterator, List, Optional, Tuple
 import numpy as np
 
 from ..core.metrics import IngestMetrics, IOStats
-from ..storage.store import _fsync_dir   # one durability primitive, one home
+from ..obs import span as _span
+from ..storage.store import fsync   # one durability primitive, one home
 
 __all__ = ["WriteAheadLog", "WALCorruptionError", "FSYNC_POLICIES"]
 
@@ -170,8 +171,8 @@ class WriteAheadLog:
         if self.fsync != "never":
             # the directory entry must be durable too, or a power loss
             # can make every fsynced record vanish with its file
-            os.fsync(self._f.fileno())
-            _fsync_dir(self.root)
+            fsync(self._f, "wal")
+            fsync(self.root, "dir")
         self._live_bytes = HEADER_SIZE
 
     # ----------------------------------------------------------------- append
@@ -198,12 +199,17 @@ class WriteAheadLog:
         the caller may then ack the insert.  ``ids`` (global row ids) are
         logged alongside so replay restores exactly the ids the batch was
         acked with — the sharded router's ids are not reconstructible
-        from the shard-local stream."""
-        rec = self._encode(start_row, raw, ts, ids)
-        self._f.write(rec)
-        self._f.flush()
-        if self.fsync == "always":
-            os.fsync(self._f.fileno())
+        from the shard-local stream.  Traced as ``wal.append`` over
+        ``wal.encode`` (record and checksum), ``wal.write`` (write and
+        flush) and, under ``"always"``, ``fsync``."""
+        with _span("wal.append", rows=len(raw)):
+            with _span("wal.encode"):
+                rec = self._encode(start_row, raw, ts, ids)
+            with _span("wal.write", bytes=len(rec)):
+                self._f.write(rec)
+                self._f.flush()
+            if self.fsync == "always":
+                fsync(self._f, "wal")
         self._live_bytes += len(rec)
         if self.io is not None:
             self.io.write_bytes(len(rec))
@@ -221,21 +227,23 @@ class WriteAheadLog:
         ``tail`` — the (start_row, raw, ts, ids) batches not yet covered by
         the committed manifest.  Called *after* the manifest commit, so a
         crash at any point leaves a replayable log.  The new file is always
-        fsynced before the old ones are deleted, regardless of policy."""
-        old = [f for _, f in _wal_files(self.root)]
-        self._f.close()
-        self._seq += 1
-        self._open_active()
-        for start_row, raw, ts, ids in tail:
-            rec = self._encode(start_row, raw, ts, ids)
-            self._f.write(rec)
-            self._live_bytes += len(rec)
-        self._f.flush()
-        os.fsync(self._f.fileno())
-        _fsync_dir(self.root)    # new file durable BEFORE the old ones go
-        for f in old:
-            os.unlink(os.path.join(self.root, f))
-        _fsync_dir(self.root)
+        fsynced before the old ones are deleted, regardless of policy.
+        Traced as one ``wal.rotate`` span."""
+        with _span("wal.rotate", batches=len(tail)):
+            old = [f for _, f in _wal_files(self.root)]
+            self._f.close()
+            self._seq += 1
+            self._open_active()
+            for start_row, raw, ts, ids in tail:
+                rec = self._encode(start_row, raw, ts, ids)
+                self._f.write(rec)
+                self._live_bytes += len(rec)
+            self._f.flush()
+            fsync(self._f, "wal")
+            fsync(self.root, "dir")  # new file durable BEFORE the old go
+            for f in old:
+                os.unlink(os.path.join(self.root, f))
+            fsync(self.root, "dir")
         if self.metrics is not None:
             self.metrics.add("wal_rotations")
             self.metrics.set_gauge("wal_live_bytes", self._live_bytes)
@@ -245,7 +253,7 @@ class WriteAheadLog:
             return
         self._f.flush()
         if self.fsync != "never":
-            os.fsync(self._f.fileno())
+            fsync(self._f, "wal")
         self._f.close()
 
     # ----------------------------------------------------------------- replay

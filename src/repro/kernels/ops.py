@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import summarization as S
-from ..obs import profile as _prof
 from . import ref
 
 # jit-compiled oracle paths: eager dispatch dominated the scan cost
@@ -108,13 +107,11 @@ def mindist_batch(q_paas: jax.Array, codes: jax.Array, cfg: S.SummaryConfig,
     mode = _resolve(mode)
     scale = cfg.series_len / cfg.segments
     lower, upper = _finite_bounds(cfg.bits)
-    with _prof.profiled("mindist_batch") as done:
-        if mode == "jnp":
-            return done(_mindist_batch_jit(q_paas, codes, lower, upper,
-                                           scale=scale))
-        return done(mindist_batch_pallas(q_paas, codes.astype(jnp.int32),
-                                         lower, upper, scale=scale,
-                                         interpret=(mode == "interpret")))
+    if mode == "jnp":
+        return _mindist_batch_jit(q_paas, codes, lower, upper, scale=scale)
+    return mindist_batch_pallas(q_paas, codes.astype(jnp.int32),
+                                lower, upper, scale=scale,
+                                interpret=(mode == "interpret"))
 
 
 def mindist_batch_packed(q_paas: jax.Array, packed: jax.Array,
@@ -132,14 +129,13 @@ def mindist_batch_packed(q_paas: jax.Array, packed: jax.Array,
     mode = _resolve(mode)
     scale = cfg.series_len / cfg.segments
     lower, upper = _finite_bounds(cfg.bits)
-    with _prof.profiled("mindist_batch_packed") as done:
-        if mode == "jnp":
-            return done(_mindist_batch_packed_jit(
-                q_paas, packed, lower, upper, scale=scale,
-                w=cfg.segments, b=cfg.bits))
-        return done(unpack_mindist_batch_pallas(
-            q_paas, packed, lower, upper, w=cfg.segments, b=cfg.bits,
-            scale=scale, interpret=(mode == "interpret")))
+    if mode == "jnp":
+        return _mindist_batch_packed_jit(
+            q_paas, packed, lower, upper, scale=scale,
+            w=cfg.segments, b=cfg.bits)
+    return unpack_mindist_batch_pallas(
+        q_paas, packed, lower, upper, w=cfg.segments, b=cfg.bits,
+        scale=scale, interpret=(mode == "interpret"))
 
 
 def sax_summarize(x: jax.Array, cfg: S.SummaryConfig, mode: str = "auto"):
@@ -206,16 +202,12 @@ def scan_verify(queries: jax.Array, q_paas: jax.Array, codes: jax.Array,
     lower, upper = _finite_bounds(cfg.bits)
     if dead is None:
         dead = jnp.zeros(codes.shape[0], jnp.int32)
-    with _prof.profiled("scan_verify") as done:
-        if mode == "jnp":
-            return done(_scan_verify_jit(queries, q_paas, codes, raw,
-                                         lower, upper, bound, dead,
-                                         scale=scale, k=k))
-        return done(scan_verify_pallas(queries, q_paas,
-                                       codes.astype(jnp.int32),
-                                       raw, lower, upper, bound, dead,
-                                       scale=scale, k=k,
-                                       interpret=(mode == "interpret")))
+    if mode == "jnp":
+        return _scan_verify_jit(queries, q_paas, codes, raw, lower, upper,
+                                bound, dead, scale=scale, k=k)
+    return scan_verify_pallas(queries, q_paas, codes.astype(jnp.int32),
+                              raw, lower, upper, bound, dead, scale=scale,
+                              k=k, interpret=(mode == "interpret"))
 
 
 def mesh_scan(queries: jax.Array, q_paas: jax.Array, codes: jax.Array,
@@ -240,9 +232,7 @@ def mesh_scan(queries: jax.Array, q_paas: jax.Array, codes: jax.Array,
         ts_min = jnp.zeros(ids.shape[0], jnp.int32)
     fn = _mesh.mesh_scan_launch(mesh, axis, cfg, k=k,
                                 ts_filter=ts_filter, mode=mode)
-    with _prof.profiled("mesh_scan") as done:
-        return done(fn(codes, raw, ids, ts, ts_min, queries, q_paas,
-                       bound))
+    return fn(codes, raw, ids, ts, ts_min, queries, q_paas, bound)
 
 
 def summarize_and_key(x: jax.Array, cfg: S.SummaryConfig,

@@ -1,4 +1,4 @@
-"""Observability: unified metrics registry, query tracing, profiling.
+"""Observability: unified metrics registry, tracing, query log.
 
 One substrate under the whole serving stack:
 
@@ -8,13 +8,14 @@ One substrate under the whole serving stack:
   ``SearchStats`` into it, and :func:`describe_metrics` is the one
   scrape point.
 * :mod:`repro.obs.trace` — per-query span trees (plan → prune → scan →
-  verify → merge, plus per-shard fan-out), ring-buffered and exported
-  as Chrome/Perfetto ``trace_event`` JSON.
+  verify → merge, plus per-shard fan-out), and the write path's spans
+  (insert → WAL append → fsync; commit → segment fetch and write →
+  manifest → WAL rotation), ring-buffered, exported as Chrome/Perfetto
+  ``trace_event`` JSON and mirrored into any running
+  ``jax.profiler`` capture.
 * :mod:`repro.obs.querylog` — one structured JSON record per probe,
   size-rotated alongside the WAL; the input for workload-adaptive
   maintenance.
-* :mod:`repro.obs.profile` — gated ``jax.profiler`` capture around
-  kernel launches with a wall-clock fallback.
 
 :func:`probe` is the root scope every top-level search entry point
 opens: it tracks nesting (the sharded engine's per-shard sub-searches
@@ -116,6 +117,8 @@ def record_search(stats, prefix: str = "query") -> None:
         int(stats.leaves_pruned))
     reg.counter(f"{prefix}.scan_bytes_total").inc(int(stats.scan_bytes))
     reg.counter(f"{prefix}.buffer_rows_total").inc(int(stats.buffer_rows))
+    reg.counter(f"{prefix}.device_syncs_total").inc(int(stats.device_syncs))
+    reg.counter(f"{prefix}.d2h_bytes_total").inc(int(stats.d2h_bytes))
 
 
 @contextlib.contextmanager

@@ -15,10 +15,16 @@ Design constraints, in order:
 * **Hot-path cost.**  Tracing is off by default; a disabled tracer
   hands out one shared no-op span, so the instrumentation costs one
   attribute check per call site.  Enabled spans cost two
-  ``perf_counter`` calls and one dict append.
+  ``perf_counter`` calls, one profiler annotation and one dict append.
 * **Bounded memory.**  Finished spans land in a ring buffer
   (``collections.deque(maxlen=...)``) — sustained serving overwrites
   the oldest spans instead of growing without bound.
+* **One clock with the profiler.**  While tracing is on, every span
+  also opens a ``jax.profiler.TraceAnnotation`` of its name, so an
+  operator's own ``jax.profiler.trace`` capture shows the program's
+  spans on the profiler's host clock beside the device's programs,
+  and an idle gap on the device can be put down to the span over it
+  with no alignment step.  The off path imports nothing.
 * **Context propagation.**  The parent pointer rides a
   ``contextvars.ContextVar``, so nesting is automatic within a thread
   (and across ``asyncio`` tasks); worker threads (compactor, router
@@ -69,7 +75,7 @@ class Span:
     ``args`` — visible in the Perfetto span detail pane."""
 
     __slots__ = ("tracer", "name", "args", "span_id", "parent_id",
-                 "tid", "t0_us", "dur_us", "_token")
+                 "tid", "t0_us", "dur_us", "_token", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self.tracer = tracer
@@ -81,6 +87,7 @@ class Span:
         self.t0_us = 0.0
         self.dur_us = 0.0
         self._token = None
+        self._mirror = None
 
     def set(self, **args) -> None:
         self.args.update(args)
@@ -92,12 +99,15 @@ class Span:
         self.parent_id = parent.span_id if parent is not None else 0
         self.tid = threading.get_ident() & 0x7FFFFFFF
         self._token = _current.set(self)
+        self._mirror = tr._annotation(self.name)
+        self._mirror.__enter__()
         self.t0_us = (time.perf_counter() - tr.epoch) * 1e6
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur_us = (time.perf_counter() - self.tracer.epoch) * 1e6 \
             - self.t0_us
+        self._mirror.__exit__(None, None, None)
         _current.reset(self._token)
         self.tracer._record(self)
         return False
@@ -114,6 +124,7 @@ class Tracer:
         self._ring: deque = deque(maxlen=capacity)
         self._id = 0
         self.dropped = 0          # spans overwritten by the ring bound
+        self._annotation = None   # jax.profiler.TraceAnnotation, once on
 
     def _next_id(self) -> int:
         with self._lock:
@@ -137,6 +148,9 @@ class Tracer:
         return Span(self, name, args)
 
     def enable(self) -> None:
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:

@@ -118,13 +118,16 @@ def as_budget(budget: Union[None, int, dict, Budget]) -> Optional[Budget]:
 
 def _drain(plan: ScanPlan, queries_np: np.ndarray, *, k: int,
            budget: Optional[Budget], bsf, radius_leaves: int,
-           chunk: int, io, mindist_fn, plan_ms: float = 0.0
+           chunk: int, io, mindist_fn, plan_ms: float = 0.0,
+           stats: SearchStats
            ) -> Iterator[Tuple[np.ndarray, np.ndarray, SearchStats]]:
-    """The budgeted frontier drain (generator of improving snapshots)."""
+    """The budgeted frontier drain (generator of improving snapshots),
+    continuing ``stats``, which already counts the planning's device
+    reads."""
     queries_j, q_paas_j = device_queries(queries_np, plan.q_paas)
     nq = queries_np.shape[0]
     pool = KnnPool(nq, k, ext=bsf)
-    stats = SearchStats(exact=False, queries=nq)
+    stats.exact, stats.queries = False, nq
     stats.candidates_per_query = np.zeros(nq, np.int64)
     stats.leaves_per_query = np.zeros(nq, np.int64)
     if plan_ms:
@@ -153,7 +156,7 @@ def _drain(plan: ScanPlan, queries_np: np.ndarray, *, k: int,
     for entry in sorted_entries:
         with _span("seed", radius_leaves=radius_leaves):
             alive, offs_all, idx0 = _seed_sorted(
-                entry, queries_j, q_paas_j, pool,
+                entry, queries_j, q_paas_j, pool, stats,
                 radius_leaves=radius_leaves, io=io)
         stats.candidates += len(np.unique(idx0))
         stats.candidates_per_query += idx0.shape[1]
@@ -312,15 +315,17 @@ def approx_knn(partitions: Sequence[Partition], queries,
     bit-identical to the exact pipeline and ``gap == 0``.
     """
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
+    stats = SearchStats()
     t0 = time.perf_counter()
-    q_paas = query_paas(queries_np, cfg.segments)
+    q_paas = query_paas(queries_np, cfg.segments, stats)
     plan = build_plan(partitions, q_paas, ts_min=ts_min,
-                      temporal_prune=temporal_prune, io=io)
+                      temporal_prune=temporal_prune, io=io, stats=stats)
     plan_ms = (time.perf_counter() - t0) * 1e3
     out = None
     for out in _drain(plan, queries_np, k=k, budget=as_budget(budget),
                       bsf=bsf, radius_leaves=radius_leaves, chunk=chunk,
-                      io=io, mindist_fn=mindist_fn, plan_ms=plan_ms):
+                      io=io, mindist_fn=mindist_fn, plan_ms=plan_ms,
+                      stats=stats):
         pass
     return out
 
@@ -346,11 +351,13 @@ def progressive_knn(partitions: Sequence[Partition], queries,
     generator abandons the rest of the scan on ``close()``.
     """
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
+    stats = SearchStats()
     t0 = time.perf_counter()
-    q_paas = query_paas(queries_np, cfg.segments)
+    q_paas = query_paas(queries_np, cfg.segments, stats)
     plan = build_plan(partitions, q_paas, ts_min=ts_min,
-                      temporal_prune=temporal_prune, io=io)
+                      temporal_prune=temporal_prune, io=io, stats=stats)
     plan_ms = (time.perf_counter() - t0) * 1e3
     yield from _drain(plan, queries_np, k=k, budget=as_budget(budget),
                       bsf=bsf, radius_leaves=radius_leaves, chunk=chunk,
-                      io=io, mindist_fn=mindist_fn, plan_ms=plan_ms)
+                      io=io, mindist_fn=mindist_fn, plan_ms=plan_ms,
+                      stats=stats)

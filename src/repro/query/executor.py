@@ -17,6 +17,13 @@ bits every entry point historically returned; ``scan_mode`` opts into
 the fused :mod:`repro.kernels.scan_verify` Pallas kernel (one pass:
 bound + masked verify + on-device top-k), which is the TPU serving
 path and is validated against the eager chain in the kernel tests.
+
+The host's share of a probe is named by three spans inside its stages:
+``exec.launch`` over the preparation and enqueueing of a device program
+(row gathers, padding, host-to-device copies, the dispatch), ``exec.sync``
+over every read of a device result (:func:`~repro.query.merger.to_host`,
+counted in ``SearchStats.device_syncs``), and ``exec.pool`` over every
+host-side pool update.
 """
 from __future__ import annotations
 
@@ -27,7 +34,7 @@ import numpy as np
 
 from ..core import summarization as S
 from ..obs import record_search, span as _span
-from .merger import KnnPool, SearchStats
+from .merger import KnnPool, SearchStats, to_host
 from .partition import Partition
 from .planner import ScanEntry, ScanPlan, build_plan
 
@@ -54,16 +61,20 @@ def device_queries(queries: np.ndarray, q_paas: np.ndarray):
     return jnp.asarray(_pad_batch(queries)), jnp.asarray(_pad_batch(q_paas))
 
 
-def query_paas(queries: np.ndarray, segments: int) -> np.ndarray:
+def query_paas(queries: np.ndarray, segments: int,
+               stats: Optional[SearchStats] = None) -> np.ndarray:
     """Host PAAs ``[Q, w]`` of a query batch, computed on the padded
-    batch (:func:`_pad_batch`)."""
+    batch (:func:`_pad_batch`).  They are the planner's input, so their
+    device read is traced as a ``plan`` span of its own."""
     import jax.numpy as jnp
-    paas = S.paa(jnp.asarray(_pad_batch(queries)), segments)
-    return np.asarray(paas)[:queries.shape[0]]
+    with _span("plan", stage="paa", queries=queries.shape[0]):
+        paas = S.paa(jnp.asarray(_pad_batch(queries)), segments)
+        return to_host(paas, stats)[:queries.shape[0]]
 
 
 def buffer_topk(queries_j, rows: np.ndarray, offs: np.ndarray, k: int,
-                io=None) -> Tuple[np.ndarray, np.ndarray]:
+                io=None, stats: Optional[SearchStats] = None
+                ) -> Tuple[np.ndarray, np.ndarray]:
     """Brute-force per-query ``[Q, k]`` pools over unsorted rows with
     the verification kernel — THE buffer-scan contract (stable sort,
     (inf, -1) padding) shared by the exact executor and the snapshot's
@@ -77,8 +88,9 @@ def buffer_topk(queries_j, rows: np.ndarray, offs: np.ndarray, k: int,
         return best_d, best_off
     if io is not None:
         io.seq_read(len(rows))
-    d = np.asarray(S.euclidean_sq_batch(queries_j,
-                                        jnp.asarray(rows)))     # [Q, M]
+    with _span("exec.launch"):
+        d = S.euclidean_sq_batch(queries_j, jnp.asarray(rows))
+    d = to_host(d, stats)                                   # [Q, M]
     sel = np.argsort(d, axis=1, kind="stable")[:, :k]
     take = min(k, d.shape[1])
     best_d[:, :take] = np.take_along_axis(d, sel, axis=1)[:, :take]
@@ -97,15 +109,17 @@ def _scan_buffer(entry: ScanEntry, queries_j, k: int,
         rows, offs = rows[keep], offs[keep]
     if len(rows) == 0:
         return
-    new_d, new_off = buffer_topk(queries_j, rows, offs, k, io=io)
-    pool.update_batch(new_d[:pool.nq], new_off[:pool.nq])
+    new_d, new_off = buffer_topk(queries_j, rows, offs, k, io=io,
+                                 stats=stats)
+    with _span("exec.pool"):
+        pool.update_batch(new_d[:pool.nq], new_off[:pool.nq])
     stats.buffer_rows += len(rows)
     stats.candidates_per_query += len(rows)
 
 
 def _seed_sorted(entry: ScanEntry, queries_j, q_paas_j,
-                 pool: KnnPool, *, radius_leaves: int, io
-                 ) -> Tuple[Optional[np.ndarray], np.ndarray, np.ndarray]:
+                 pool: KnnPool, stats: SearchStats, *, radius_leaves: int,
+                 io) -> Tuple[Optional[np.ndarray], np.ndarray, np.ndarray]:
     """Seed the pool from the leaves around each query's z-order slot
     (the Algorithm-4 probe).  Returns ``(alive, offs_all, idx0)`` for
     the scan that follows.  Shared by the exact path and the budgeted
@@ -120,20 +134,23 @@ def _seed_sorted(entry: ScanEntry, queries_j, q_paas_j,
             alive = ts >= entry.ts_min
     offs_all = part.report_ids()
     idx0 = part.seed_window(queries_j, radius_leaves=radius_leaves, io=io,
-                            q_paas=q_paas_j)
-    rows0 = part.series_rows(idx0.reshape(-1), io=io)
+                            q_paas=q_paas_j, stats=stats)
     # canonical bits: seed distances add in the verifier's fixed order
     # (``S.sum_sq``) so returned values never depend on partitioning —
     # one gather + one batched op for the whole pool
-    rows0 = jnp.asarray(rows0).reshape(idx0.shape + (-1,))    # [Q, C, L]
-    d0 = np.asarray(S.sq_dist(rows0, queries_j[:, None, :]), np.float32)
+    with _span("exec.launch"):
+        rows0 = part.series_rows(idx0.reshape(-1), io=io)
+        rows0 = jnp.asarray(rows0).reshape(idx0.shape + (-1,))  # [Q, C, L]
+        d0 = S.sq_dist(rows0, queries_j[:, None, :])
+    d0 = np.asarray(to_host(d0, stats), np.float32)
     if alive is not None:
         d0 = np.where(alive[idx0], d0, np.inf)
         offs0 = np.where(alive[idx0], offs_all[idx0], -1)
     else:
         offs0 = offs_all[idx0]
-    for qi in range(nq):
-        pool.update(qi, d0[qi], offs0[qi])
+    with _span("exec.pool"):
+        for qi in range(nq):
+            pool.update(qi, d0[qi], offs0[qi])
     return alive, offs_all, idx0[:nq]
 
 
@@ -190,7 +207,8 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     row_idx = row_idx[row_idx < part.n]
     nbytes = len(row_idx) * part.cfg.segments
     if fused is not None:
-        codes_blk = part.codes_rows(row_idx, io=io)
+        with _span("exec.launch"):
+            codes_blk = part.codes_rows(row_idx, io=io)
         t0 = time.perf_counter()
         with _span("verify", rows=len(row_idx), fused=True) as vsp:
             before = stats.candidates
@@ -213,18 +231,19 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     # (edge rows, dropped below), so groups cut short by a run's last
     # leaf reuse the programs of whole ones
     nr = len(row_idx)
-    if (part.is_packed
-            and getattr(mindist_fn, "_coconut_default_mindist", False)):
-        from ..kernels import ops
-        packed_blk = _bound_rows(part, row_idx, io, packed=True)
-        md = ops.mindist_batch_packed(q_paas_j, jnp.asarray(packed_blk),
-                                      part.cfg)
-    else:
-        codes_blk = _bound_rows(part, row_idx, io, packed=False)
-        if part.backend != "device":
-            codes_blk = jnp.asarray(codes_blk)
-        md = mindist_fn(q_paas_j, codes_blk)
-    md = np.asarray(md)[:nq, :nr]                             # [Q, B]
+    with _span("exec.launch"):
+        if (part.is_packed
+                and getattr(mindist_fn, "_coconut_default_mindist", False)):
+            from ..kernels import ops
+            packed_blk = _bound_rows(part, row_idx, io, packed=True)
+            md = ops.mindist_batch_packed(q_paas_j,
+                                          jnp.asarray(packed_blk), part.cfg)
+        else:
+            codes_blk = _bound_rows(part, row_idx, io, packed=False)
+            if part.backend != "device":
+                codes_blk = jnp.asarray(codes_blk)
+            md = mindist_fn(q_paas_j, codes_blk)
+    md = to_host(md, stats)[:nq, :nr]                         # [Q, B]
     live = md < pool.bound()[:, None]
     if alive is not None:
         live &= alive[row_idx][None, :]
@@ -238,27 +257,29 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     with _span("verify", rows=len(block)) as vsp:
         nb = len(block)
         pad = _verify_rows(nb) - nb
-        if part.backend == "device":
-            # pad the device gather itself (edge rows, dropped below)
-            rows = part.series_rows(np.pad(block, (0, pad), mode="edge"),
-                                    io=io)
-            if io is not None:
-                io.seq_read(nb)
-        else:
-            rows = np.pad(np.asarray(part.series_rows(block, io=io)),
-                          ((0, pad), (0, 0)))
-        dd = np.asarray(S.euclidean_sq_batch(
-            queries_j, jnp.asarray(rows)))[:nq, :nb]           # [Q, B]
+        with _span("exec.launch"):
+            if part.backend == "device":
+                # pad the device gather itself (edge rows, dropped below)
+                rows = part.series_rows(
+                    np.pad(block, (0, pad), mode="edge"), io=io)
+                if io is not None:
+                    io.seq_read(nb)
+            else:
+                rows = np.pad(np.asarray(part.series_rows(block, io=io)),
+                              ((0, pad), (0, 0)))
+            dd = S.euclidean_sq_batch(queries_j, jnp.asarray(rows))
+        dd = to_host(dd, stats)[:nq, :nb]                       # [Q, B]
         nbytes += len(block) * part.cfg.series_len * 4
         stats.candidates += len(block)
         union_mark[block // leaf] = True
-        for qi in range(nq):
-            m = mask[qi]
-            if not m.any():
-                continue
-            stats.candidates_per_query[qi] += int(m.sum())
-            leaf_mark[qi, block[m] // leaf] = True
-            pool.update(qi, dd[qi][m], offs_all[block[m]])
+        with _span("exec.pool"):
+            for qi in range(nq):
+                m = mask[qi]
+                if not m.any():
+                    continue
+                stats.candidates_per_query[qi] += int(m.sum())
+                leaf_mark[qi, block[m] // leaf] = True
+                pool.update(qi, dd[qi][m], offs_all[block[m]])
         vsp.set(candidates=len(block),
                 raw_bytes=len(block) * part.cfg.series_len * 4)
     stats.add_timing("verify", (time.perf_counter() - t0) * 1e3)
@@ -282,7 +303,7 @@ def _scan_sorted(entry: ScanEntry, queries_j, q_paas_j, k: int,
 
     with _span("seed", radius_leaves=radius_leaves):
         alive, offs_all, _ = _seed_sorted(entry, queries_j, q_paas_j, pool,
-                                          radius_leaves=radius_leaves,
+                                          stats, radius_leaves=radius_leaves,
                                           io=io)
 
     # -- leaf-granular pruning against the fence bounds --------------------
@@ -342,33 +363,35 @@ def _verify_fused(entry: ScanEntry, queries_j, q_paas_j, codes_blk,
     from ..kernels import ops
     part = entry.partition
     nq = pool.nq
-    rows = part.series_rows(row_idx, io=io)
     bound = pool.bound()
     bound = np.pad(bound, (0, queries_j.shape[0] - nq), mode="edge")
     if alive is not None:
         dead = ~alive[row_idx]
     else:
         dead = None
-    d, li, counts, union = ops.scan_verify(
-        queries_j, q_paas_j, jnp.asarray(codes_blk), jnp.asarray(rows),
-        jnp.asarray(bound), part.cfg, k=min(k, len(row_idx)),
-        mode=scan_mode,
-        dead=None if dead is None else jnp.asarray(dead))
-    d = np.asarray(d, np.float32)[:nq]
-    li = np.asarray(li)[:nq]
-    counts = np.asarray(counts)[:nq]
+    with _span("exec.launch"):
+        rows = part.series_rows(row_idx, io=io)
+        d, li, counts, union = ops.scan_verify(
+            queries_j, q_paas_j, jnp.asarray(codes_blk), jnp.asarray(rows),
+            jnp.asarray(bound), part.cfg, k=min(k, len(row_idx)),
+            mode=scan_mode,
+            dead=None if dead is None else jnp.asarray(dead))
+    d = np.asarray(to_host(d, stats), np.float32)[:nq]
+    li = to_host(li, stats)[:nq]
+    counts = to_host(counts, stats)[:nq]
     live = 0
-    for qi in range(nq):
-        stats.candidates_per_query[qi] += int(counts[qi])
-        live += int(counts[qi])
-        fin = np.isfinite(d[qi])
-        if not fin.any():
-            continue
-        rows_qi = row_idx[li[qi][fin]]
-        leaf_mark[qi, rows_qi // part.leaf_size] = True
-        union_mark[rows_qi // part.leaf_size] = True
-        pool.update(qi, d[qi][fin], offs_all[rows_qi])
-    stats.candidates += int(union)
+    with _span("exec.pool"):
+        for qi in range(nq):
+            stats.candidates_per_query[qi] += int(counts[qi])
+            live += int(counts[qi])
+            fin = np.isfinite(d[qi])
+            if not fin.any():
+                continue
+            rows_qi = row_idx[li[qi][fin]]
+            leaf_mark[qi, rows_qi // part.leaf_size] = True
+            union_mark[rows_qi // part.leaf_size] = True
+            pool.update(qi, d[qi][fin], offs_all[rows_qi])
+    stats.candidates += int(to_host(union, stats))
     if io is not None:
         io.seq_read(len(row_idx))
     return live
@@ -378,7 +401,8 @@ def execute(plan: ScanPlan, queries, *, k: int = 1,
             bsf: Optional[np.ndarray] = None,
             radius_leaves: int = 1, chunk: int = 4096,
             io=None, mindist_fn=None,
-            scan_mode: Optional[str] = None
+            scan_mode: Optional[str] = None,
+            stats: Optional[SearchStats] = None
             ) -> Tuple[np.ndarray, np.ndarray, SearchStats]:
     """Run a :class:`ScanPlan` and return (dists ``[Q, k]``, ids
     ``[Q, k]``, :class:`SearchStats`).
@@ -395,6 +419,9 @@ def execute(plan: ScanPlan, queries, *, k: int = 1,
     the device-resident mesh launch is orchestrated ABOVE this seam (in
     the sharded fan-out) and this executor IS its threaded fallback, so
     a mesh request that reaches here runs the canonical eager chain.
+    ``stats``: the accounting to continue (the device reads that
+    summarized and planned the batch are already in it); a fresh one
+    when None.
     """
     if scan_mode == "mesh":
         scan_mode = None
@@ -402,7 +429,8 @@ def execute(plan: ScanPlan, queries, *, k: int = 1,
     nq = queries_np.shape[0]
     queries_j, q_paas_j = device_queries(queries_np, plan.q_paas)
     pool = KnnPool(nq, k, ext=bsf)
-    stats = SearchStats(exact=True, queries=nq)
+    stats = SearchStats() if stats is None else stats
+    stats.exact, stats.queries = True, nq
     stats.candidates_per_query = np.zeros(nq, np.int64)
     stats.leaves_per_query = np.zeros(nq, np.int64)
     live_pairs = 0
@@ -461,14 +489,15 @@ def exact_knn(partitions: Sequence[Partition], queries,
     """Plan + execute in one call — the pipeline every exact-search entry
     point (tree, snapshot, sharded shard, mmap segment) delegates to."""
     queries_np = np.atleast_2d(np.asarray(queries, np.float32))
+    stats = SearchStats()
     t0 = time.perf_counter()
-    q_paas = query_paas(queries_np, cfg.segments)
+    q_paas = query_paas(queries_np, cfg.segments, stats)
     plan = build_plan(partitions, q_paas, ts_min=ts_min,
-                      temporal_prune=temporal_prune, io=io)
+                      temporal_prune=temporal_prune, io=io, stats=stats)
     plan_ms = (time.perf_counter() - t0) * 1e3
     d, off, stats = execute(plan, queries_np, k=k, bsf=bsf,
                             radius_leaves=radius_leaves, chunk=chunk,
                             io=io, mindist_fn=mindist_fn,
-                            scan_mode=scan_mode)
+                            scan_mode=scan_mode, stats=stats)
     stats.add_timing("plan", plan_ms)
     return d, off, stats

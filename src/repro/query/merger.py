@@ -21,7 +21,10 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["SearchStats", "KnnPool", "merge_topk", "merge_pools"]
+from ..obs import span as _span
+
+__all__ = ["SearchStats", "KnnPool", "merge_topk", "merge_pools",
+           "to_host"]
 
 
 @dataclasses.dataclass
@@ -55,6 +58,12 @@ class SearchStats:
     rather than on the bounds; ``scan_bytes`` counts the code + raw
     bytes the leaf scan streamed (the currency of ``max_bytes``,
     identical across backends — seeds and buffer scans are uncharged).
+
+    Host/device traffic: ``device_syncs`` counts the reads of a device
+    result the pipeline waited for (each blocks the host until the
+    device has computed it and copied it over, :func:`to_host`), and
+    ``d2h_bytes`` the bytes those reads copied.  They count whether or
+    not tracing is on.
     """
     candidates: int = 0          # raw series whose true ED was computed
     pruned_frac: float = 0.0     # fraction of (query, row) pairs pruned
@@ -72,6 +81,8 @@ class SearchStats:
     buffer_rows: int = 0         # unsorted buffer rows brute-force scanned
     scan_bytes: int = 0          # code+raw bytes streamed by the leaf scan
     budget_exhausted: bool = False   # drain stopped on the budget
+    device_syncs: int = 0        # device results read back to the host
+    d2h_bytes: int = 0           # bytes those reads copied
     gap: Optional[np.ndarray] = None          # [Q] certified epsilon bound
     lb_unvisited: Optional[np.ndarray] = None  # [Q] min unvisited-leaf lb
     # Observability riders (never affect answers): per-stage wall times
@@ -105,12 +116,33 @@ class SearchStats:
         self.partitions_pruned += other.partitions_pruned
         self.buffer_rows += other.buffer_rows
         self.scan_bytes += other.scan_bytes
+        self.device_syncs += other.device_syncs
+        self.d2h_bytes += other.d2h_bytes
         self.budget_exhausted = (self.budget_exhausted
                                  or other.budget_exhausted)
         for stage, ms in other.timings.items():
             self.add_timing(stage, ms)
         for part, ids in other.leaf_touches.items():
             self.touch_leaves(part, ids)
+
+
+def to_host(x, stats: Optional[SearchStats]) -> np.ndarray:
+    """``x`` as a host array: THE device-to-host read of the query
+    pipeline.  A device result is waited for and copied inside one
+    ``exec.sync`` span (attribute ``bytes``) and counted in ``stats``
+    (``device_syncs``, ``d2h_bytes``); a host array passes through
+    uncounted.  Every read of a device result on the probe path goes
+    through here, so the span time is the host's time blocked on the
+    device and the count is the number of round trips."""
+    if isinstance(x, np.ndarray):
+        return np.asarray(x)
+    nbytes = int(x.nbytes)
+    with _span("exec.sync", bytes=nbytes):
+        out = np.asarray(x)
+    if stats is not None:
+        stats.device_syncs += 1
+        stats.d2h_bytes += nbytes
+    return out
 
 
 def merge_topk(dists: np.ndarray, offsets: np.ndarray, k: int

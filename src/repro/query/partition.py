@@ -35,6 +35,7 @@ import numpy as np
 
 from ..core import summarization as S
 from ..core.metrics import IOStats
+from .merger import SearchStats, to_host
 
 __all__ = ["Partition"]
 
@@ -120,14 +121,16 @@ class Partition:
         return self.cfg.segments
 
     # ----------------------------------------------------------- sorted access
-    def leaf_fences(self, io: Optional[IOStats] = None
+    def leaf_fences(self, io: Optional[IOStats] = None,
+                    stats: Optional[SearchStats] = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """(leaf-first keys ``[n_leaves, n_words]`` uint32, last key
         ``[n_words]``) — the implicit internal-node layer the planner
-        turns into per-leaf code envelopes."""
+        turns into per-leaf code envelopes.  ``stats`` counts a tree's
+        device reads."""
         if self.kind == "tree":
-            fences = np.asarray(self.source.fences)
-            last = np.asarray(self.source.keys[-1:])[0]
+            fences = to_host(self.source.fences, stats)
+            last = to_host(self.source.keys[-1:], stats)[0]
         else:
             fences = np.asarray(self.source.fences)
             last = np.asarray(self.source.keys[self.n - 1])
@@ -137,7 +140,8 @@ class Partition:
 
     def seed_window(self, queries, *, radius_leaves: int = 1,
                     io: Optional[IOStats] = None,
-                    q_paas=None) -> np.ndarray:
+                    q_paas=None,
+                    stats: Optional[SearchStats] = None) -> np.ndarray:
         """Row indices ``[Q, span]`` of the rows around each query's
         z-order insertion point (the Algorithm-4 probe that seeds the
         exact scan's best-so-far pool).
@@ -148,14 +152,15 @@ class Partition:
         — so the probe windows (and hence budgeted answers) are
         identical across backends.  ``q_paas``: optional precomputed
         query PAA (the plan already holds it) — avoids a second
-        summarization on the segment path."""
+        summarization on the segment path.  ``stats`` counts the tree's
+        device read of the window."""
         import jax.numpy as jnp
         if self.kind == "tree":
             from ..core.tree import _approx_candidates_batch
             _, idx = _approx_candidates_batch(
                 self.source, jnp.asarray(queries),
                 radius_leaves=radius_leaves)
-            idx = np.asarray(idx)
+            idx = to_host(idx, stats)
         else:
             from ..core import keys as K
             seg = self.source

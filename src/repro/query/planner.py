@@ -35,6 +35,7 @@ import numpy as np
 
 from ..core import summarization as S
 from ..obs import span as _span
+from .merger import SearchStats, to_host
 from .partition import Partition
 
 __all__ = ["ScanPlan", "ScanEntry", "build_plan", "leaf_envelopes",
@@ -78,15 +79,17 @@ def leaf_envelopes(fences: np.ndarray, last_key: np.ndarray,
 
 
 def envelope_mindist_sq(q_paas: np.ndarray, code_lo: np.ndarray,
-                        code_hi: np.ndarray, cfg: S.SummaryConfig
+                        code_hi: np.ndarray, cfg: S.SummaryConfig,
+                        stats: Optional[SearchStats] = None
                         ) -> np.ndarray:
     """Squared mindist lower bounds queries x envelopes: ``[Q, n]``.
 
     <= the true ED^2 to ANY series whose SAX word lies inside the
     (code_lo, code_hi) envelope per segment — hence to any row of the
     leaf (or partition) whose key interval produced the envelope.
+    ``stats`` counts the device reads of the region bounds.
     """
-    lower, upper = (np.asarray(a) for a in S.region_bounds(cfg.bits))
+    lower, upper = (to_host(a, stats) for a in S.region_bounds(cfg.bits))
     lb = lower[code_lo]                      # [n, w] envelope lower edges
     ub = upper[code_hi]
     q = np.asarray(q_paas, np.float32)[:, None, :]           # [Q, 1, w]
@@ -97,7 +100,8 @@ def envelope_mindist_sq(q_paas: np.ndarray, code_lo: np.ndarray,
             * np.sum(d * d, axis=-1)).astype(np.float32)
 
 
-def _partition_envelopes(part: Partition, io=None):
+def _partition_envelopes(part: Partition, io=None,
+                         stats: Optional[SearchStats] = None):
     """(leaf env_lo, leaf env_hi, partition (lo, hi) envelope) for a
     sorted partition, cached on the immutable source object: fences
     never change for a frozen run/segment, so the unpackbits prefix
@@ -108,7 +112,7 @@ def _partition_envelopes(part: Partition, io=None):
     cached = getattr(src, "_coconut_env_cache", None)
     if cached is not None and cached[0] == key:
         return cached[1]
-    fences, last = part.leaf_fences(io=io)
+    fences, last = part.leaf_fences(io=io, stats=stats)
     env_lo, env_hi = leaf_envelopes(fences, last, part.cfg)
     part_env = leaf_envelopes(fences[:1], last, part.cfg)
     out = (env_lo, env_hi, part_env)
@@ -188,13 +192,14 @@ def build_device_layout(row_counts: Sequence[int], *, n_devices: int,
 def build_plan(partitions: Sequence[Partition], q_paas: np.ndarray, *,
                ts_min: Optional[int] = None,
                temporal_prune: bool = True,
-               io=None) -> ScanPlan:
+               io=None, stats: Optional[SearchStats] = None) -> ScanPlan:
     """Plan the scan: filter by window, bound by fences, order by cost.
 
     Unsorted buffer partitions come first (they are the newest rows and
     have no fences to bound them), then sorted partitions cheapest
     fence bound first; ties keep the caller's order (newest-first for
-    LSM runs).  Empty partitions are dropped.
+    LSM runs).  Empty partitions are dropped.  ``stats`` counts the
+    planner's device reads.
     """
     q_paas = np.atleast_2d(np.asarray(q_paas, np.float32))
     nq = q_paas.shape[0]
@@ -217,12 +222,13 @@ def build_plan(partitions: Sequence[Partition], q_paas: np.ndarray, *,
                 buffers.append(ScanEntry(part, eff_ts,
                                          np.zeros(nq, np.float32), None))
                 continue
-            env_lo, env_hi, part_env = _partition_envelopes(part, io=io)
+            env_lo, env_hi, part_env = _partition_envelopes(
+                part, io=io, stats=stats)
             leaf_bounds = envelope_mindist_sq(q_paas, env_lo, env_hi,
-                                              part.cfg)
+                                              part.cfg, stats)
             # the partition-level bound is the envelope of (first, last) key
             part_bound = envelope_mindist_sq(q_paas, *part_env,
-                                             part.cfg)[:, 0]
+                                             part.cfg, stats)[:, 0]
             sorted_entries.append(ScanEntry(part, eff_ts, part_bound,
                                             leaf_bounds))
         order = np.argsort([e.part_bound.mean() for e in sorted_entries],

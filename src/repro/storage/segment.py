@@ -59,6 +59,7 @@ import numpy as np
 
 from ..core import summarization as S
 from ..core.metrics import IOStats
+from ..obs import span as _span
 from .packing import (PackedCodes, PackedKeys, encode_keys, pack_codes,
                       packed_code_width)
 
@@ -318,7 +319,8 @@ class SegmentWriter:
         self._f.seek(0)
         self._f.write(header)
         self._f.flush()
-        os.fsync(self._f.fileno())
+        from .store import fsync        # store imports this module
+        fsync(self._f, "segment")
         self._f.close()
         if self.io is not None:
             self.io.write_bytes(HEADER_SIZE + FOOTER_SIZE)
@@ -356,7 +358,10 @@ def write_segment(path: str, tree, *, io: Optional[IOStats] = None,
     """Persist an in-memory ``CoconutTree`` as one segment file.
 
     One large sequential write per column — the O(N/B) sequential-write
-    cost of the paper's bulk load, now against a real file.
+    cost of the paper's bulk load, now against a real file.  Traced as
+    ``segment.fetch`` (the columns copied off the device, which waits
+    for the work that computes them) then ``segment.write`` (encode,
+    write and the ``fsync``).
     """
     has_ts = tree.timestamps is not None
     has_raw = tree.raw is not None or tree.raw_ref is not None
@@ -366,15 +371,19 @@ def write_segment(path: str, tree, *, io: Optional[IOStats] = None,
                       has_timestamps=has_ts, has_raw=has_raw,
                       has_ids=has_ids, io=io, version=version)
     try:
-        w.append(np.asarray(tree.keys), np.asarray(tree.codes),
-                 np.asarray(tree.paas), np.asarray(tree.offsets),
-                 timestamps=(np.asarray(tree.timestamps)
-                             if has_ts else None),
-                 raw=np.asarray(tree.raw) if tree.materialized else None,
-                 ids=np.asarray(tree.ids) if has_ids else None)
-        if has_raw and not tree.materialized:
-            w.append_raw(np.asarray(tree.raw_ref))
-        w.finalize()
+        with _span("segment.fetch", rows=tree.n):
+            cols = [np.asarray(c) for c in (tree.keys, tree.codes,
+                                            tree.paas, tree.offsets)]
+            ts = np.asarray(tree.timestamps) if has_ts else None
+            raw = np.asarray(tree.raw) if tree.materialized else None
+            ids = np.asarray(tree.ids) if has_ids else None
+            raw_ref = (np.asarray(tree.raw_ref)
+                       if has_raw and not tree.materialized else None)
+        with _span("segment.write", rows=tree.n):
+            w.append(*cols, timestamps=ts, raw=raw, ids=ids)
+            if raw_ref is not None:
+                w.append_raw(raw_ref)
+            w.finalize()
     except BaseException:
         w.abort()
         raise

@@ -31,9 +31,11 @@ from typing import Dict, List, Optional
 
 from ..core import summarization as S
 from ..core.metrics import IOStats
+from ..obs import get_registry, span as _span
 from .segment import Segment, SegmentFormatError, write_segment
 
-__all__ = ["SegmentStore", "ShardDirectory", "MANIFEST_NAME", "SHARDS_NAME"]
+__all__ = ["SegmentStore", "ShardDirectory", "MANIFEST_NAME", "SHARDS_NAME",
+           "fsync"]
 
 MANIFEST_NAME = "MANIFEST.json"
 SHARDS_NAME = "SHARDS.json"
@@ -43,12 +45,23 @@ MANIFEST_VERSION = 1
 SHARDS_VERSION = 1
 
 
-def _fsync_dir(path: str) -> None:
-    fd = os.open(path, os.O_RDONLY)
-    try:
-        os.fsync(fd)
-    finally:
-        os.close(fd)
+def fsync(target, file: str) -> None:
+    """THE durability primitive of the storage engine: fsync an open file
+    ``target``, or the directory at path ``target`` when ``file`` is
+    ``"dir"``.  ``file`` names what is synced (``wal``, ``segment``,
+    ``manifest`` or ``dir``): it is the ``file`` attribute of the
+    ``fsync`` span each call opens.  Every call also adds 1 to the
+    registry's ``io.fsyncs``, traced or not."""
+    with _span("fsync", file=file):
+        if file == "dir":
+            fd = os.open(target, os.O_RDONLY)
+            try:
+                os.fsync(fd)
+            finally:
+                os.close(fd)
+        else:
+            os.fsync(target.fileno())
+    get_registry().counter("io.fsyncs").inc()
 
 
 def write_json_atomic(path: str, obj: dict) -> None:
@@ -59,9 +72,9 @@ def write_json_atomic(path: str, obj: dict) -> None:
     with open(tmp, "w") as f:
         json.dump(obj, f, indent=1)
         f.flush()
-        os.fsync(f.fileno())
+        fsync(f, "manifest")
     os.replace(tmp, path)
-    _fsync_dir(os.path.dirname(path) or ".")
+    fsync(os.path.dirname(path) or ".", "dir")
 
 
 @dataclasses.dataclass
@@ -97,7 +110,8 @@ class SegmentStore:
     def commit_manifest(self, manifest: dict) -> None:
         """Atomic manifest replace — THE commit point for every mutation."""
         manifest = dict(manifest, version=MANIFEST_VERSION)
-        write_json_atomic(self.manifest_path, manifest)
+        with _span("manifest.commit", runs=len(manifest.get("runs", ()))):
+            write_json_atomic(self.manifest_path, manifest)
         if self.io is not None:
             self.io.rand_write(1)
 

@@ -119,6 +119,8 @@ def record_search(stats, prefix: str = "query") -> None:
     reg.counter(f"{prefix}.buffer_rows_total").inc(int(stats.buffer_rows))
     reg.counter(f"{prefix}.device_syncs_total").inc(int(stats.device_syncs))
     reg.counter(f"{prefix}.d2h_bytes_total").inc(int(stats.d2h_bytes))
+    reg.counter(f"{prefix}.device_scans_total").inc(int(stats.device_scans))
+    reg.counter(f"{prefix}.scan_waves_total").inc(int(stats.device_waves))
 
 
 @contextlib.contextmanager

@@ -10,13 +10,34 @@ Euclidean kernel, so answer *distances* are bit-identical regardless of
 how rows are partitioned — the invariant the streaming and sharded
 engines are built on.
 
+Two loops scan a sorted partition's surviving leaves, and both give the
+same answer bits (ids included) on the same rows:
+
+* Device partitions (``backend == "device"``: a ``CoconutTree`` or an
+  LSM run) with the default bound and no ``scan_mode`` run in *leaf
+  waves* (:func:`_scan_waves`).  The k-NN pool and its k-th-best bound
+  stay on the device for the whole partition; each wave of ``W`` leaves
+  is one bound program (:func:`wave_bound`: mindists straight from the
+  tree's contiguous code rows, the live mask against the device bound)
+  and one verify program per block of ``C`` live rows
+  (:func:`wave_verify`: distances of the live rows only, merged into the
+  pool by a stable top-k), with one small read a wave and one read of
+  the result.  ``W`` and ``C`` follow from the shapes so that either
+  program's intermediates stay under ``_WAVE_BYTES`` (256 MiB).
+* Everything else keeps the host leaf loop (:func:`_scan_leaf_group`, a
+  bound and a verify program per leaf group, the pool on the host):
+  mmap segments and tiered partitions, whose ``IOStats`` charges are
+  made leaf by leaf as the loop reads, an injected ``mindist_fn``, and
+  the fused ``scan_mode``.  The budgeted drain (:mod:`.approx`) calls
+  the same group scan.
+
 The default scan path keeps the eager kernel chain
 (:func:`repro.core.summarization.mindist_sq_batch` lower bounds +
 :func:`repro.core.summarization.euclidean_sq_batch` verification) whose
 bits every entry point historically returned; ``scan_mode`` opts into
 the fused :mod:`repro.kernels.scan_verify` Pallas kernel (one pass:
-bound + masked verify + on-device top-k), which is the TPU serving
-path and is validated against the eager chain in the kernel tests.
+bound + masked verify + on-device top-k), which is validated against
+the eager chain in the kernel tests.
 
 The host's share of a probe is named by three spans inside its stages:
 ``exec.launch`` over the preparation and enqueueing of a device program
@@ -27,10 +48,14 @@ host-side pool update.
 """
 from __future__ import annotations
 
+import functools
 import time
-from typing import Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..core import summarization as S
 from ..obs import record_search, span as _span
@@ -286,6 +311,254 @@ def _scan_leaf_group(entry: ScanEntry, queries_j, q_paas_j,
     return live_pairs, nbytes
 
 
+def _leaf_groups(surv: np.ndarray, per_group: int) -> List[np.ndarray]:
+    """The scan's leaf groups in visiting order: ``surv`` (cheapest bound
+    first) cut into groups of ``per_group``, each in leaf order so its
+    rows are read sequentially.  Both scan loops visit leaves in this
+    order, so rows tied at the k-th distance resolve alike on both."""
+    return [np.sort(surv[g:g + per_group])
+            for g in range(0, len(surv), per_group)]
+
+
+# Bytes the intermediates of one leaf wave may take in either of its
+# programs, counted as if nothing were fused: the bound's [Qp, rows, w]
+# float32 differences with each row's mindist and live flag, and the
+# verify's [Qp, C, L] float32 differences with its C raw rows.
+_WAVE_BYTES = 256 << 20
+
+
+def _pow2_floor(n: int) -> int:
+    return 1 << (max(1, int(n)).bit_length() - 1)
+
+
+def _wave_shape(qp: int, part: Partition) -> Tuple[int, int]:
+    """``(W, C)`` for a device partition probed with ``qp`` padded
+    queries: leaves a wave bounds and rows a verify block reads, powers
+    of two under :data:`_WAVE_BYTES` (W no more than the partition's
+    leaves rounded up to a power of two).  They follow from the shapes
+    alone, so a partition compiles one bound and one verify program per
+    query padding."""
+    cfg = part.cfg
+    rows = min(part.leaf_size, part.n)
+    width = _pow2_floor(_WAVE_BYTES // (qp * rows * (4 * cfg.segments + 5)))
+    width = min(width, 1 << (part.n_leaves - 1).bit_length())
+    cap = _pow2_floor(_WAVE_BYTES // ((qp + 1) * cfg.series_len * 4))
+    return width, min(cap, width * rows)
+
+
+def _wave_rows(order, j, n: int, leaf_size: int, width: int):
+    """Rows ``[W, R]`` of wave ``j`` of the leaf ``order``, the first row
+    of each block, and whether each row belongs to its leaf.  A leaf is
+    read as ``R = min(leaf_size, n)`` contiguous rows; the last leaf's
+    block starts early enough to end at row ``n``, and its rows of the
+    leaf before are not its own."""
+    r = min(leaf_size, n)
+    first = lax.dynamic_slice_in_dim(order, j * width, width) * leaf_size
+    start = jnp.minimum(first, n - r)
+    rows = start[:, None] + jnp.arange(r, dtype=start.dtype)[None, :]
+    return rows, start, rows >= first[:, None]
+
+
+def _pack_queries(live):
+    """A ``[Qp, B]`` mask as bit words ``[ceil(Qp / 32), B]`` uint32:
+    bit ``q % 32`` of word ``q // 32`` is query ``q``'s flag."""
+    qp, b = live.shape
+    nw = -(-qp // 32)
+    bits = jnp.pad(live, ((0, 32 * nw - qp), (0, 0))).reshape(nw, 32, b)
+    shift = jnp.arange(32, dtype=jnp.uint32)[None, :, None]
+    return (bits.astype(jnp.uint32) << shift).sum(axis=1, dtype=jnp.uint32)
+
+
+def _unpack_queries(words, qp: int):
+    """The ``[qp, C]`` mask of bit words ``[ceil(qp / 32), C]``."""
+    q = jnp.arange(qp, dtype=jnp.uint32)
+    return ((words[q // 32] >> (q % 32)[:, None]) & 1).astype(bool)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "leaf_size", "width"))
+def wave_bound(codes, q_paas, order, thresh, j, n_order, best_d, ext,
+               alive, *, cfg: S.SummaryConfig, leaf_size: int, width: int):
+    """Bound one wave: the mindists of its rows against the pool's bound.
+
+    Returns the live mask (a row of a real leaf, alive, its mindist
+    strictly below the query's bound) as bit words ``[ceil(Qp / 32),
+    W * R]`` (:func:`_pack_queries`), and the wave's counts, int32
+    ``[Qp + 2, W]``: live rows per query and leaf, live rows per leaf for
+    any query, and the stop flag in ``[Qp + 1, 0]``.  The stop flag is
+    set when ``thresh[j]``, the least bound of any leaf from this wave
+    on, reaches every query's bound: then no row of this wave or a later
+    one can be live, and the mask is empty."""
+    n, w = codes.shape
+    rows, start, own = _wave_rows(order, j, n, leaf_size, width)
+    r = rows.shape[1]
+
+    def take(a):                         # [W, R, ...]: contiguous blocks
+        return jax.vmap(lambda s: lax.dynamic_slice_in_dim(a, s, r))(start)
+
+    ok = own & ((j * width + jnp.arange(width)) < n_order)[:, None]
+    if alive is not None:
+        ok &= take(alive)
+    md = S.mindist_sq_batch(q_paas, take(codes).reshape(-1, w), cfg)
+    bound = jnp.minimum(best_d[:, -1], ext)
+    go = thresh[j] < jnp.max(bound)
+    live = (md < bound[:, None]) & (ok.reshape(-1) & go)[None, :]
+    per = live.reshape(-1, width, r).sum(axis=-1, dtype=jnp.int32)
+    union = live.any(axis=0).reshape(width, r).sum(axis=-1,
+                                                   dtype=jnp.int32)
+    stop = jnp.zeros(width, jnp.int32).at[0].set((~go).astype(jnp.int32))
+    return (_pack_queries(live),
+            jnp.concatenate([per, union[None], stop[None]]))
+
+
+@functools.partial(jax.jit, static_argnames=("leaf_size", "width", "cap"))
+def wave_verify(tree, queries, order, j, live, block, best_d, best_pos, *,
+                leaf_size: int, width: int, cap: int):
+    """Verify block ``block`` of a wave's live rows and merge it into
+    the pool.
+
+    The rows live for some query are numbered in wave order; the block
+    reads slots ``[block * C, (block + 1) * C)`` of them (slots past the
+    last re-read the last live row and are dead), so no raw row the mask
+    rules out is read.  Distances are ``S.euclidean_sq_batch``'s bits.
+    The merge is ``merge_topk``'s rule: pool entries first, then the
+    block in wave order, a row already in the pool (its seed put it
+    there) dropped, and a stable top-k keeping the earlier entry on equal
+    distances.  ``best_pos`` holds rows of this partition, ``-2 - i``
+    for entry ``i`` of the pool the partition started from, and -1 for
+    an empty slot.  ``live`` is :func:`wave_bound`'s bit words: gathered
+    by row as words, the mask keeps the distances' ``[Qp, C]`` layout
+    (a gather of a ``[Qp, B]`` mask's columns made the compiler lay the
+    distances out queries-minor, eight times their size on the TPU)."""
+    rows = _wave_rows(order, j, tree.n, leaf_size, width)[0].reshape(-1)
+    csum = jnp.cumsum((live != 0).any(axis=0), dtype=jnp.int32)
+    slot = block * cap + jnp.arange(cap, dtype=jnp.int32)
+    at = jnp.searchsorted(csum, jnp.minimum(slot, csum[-1] - 1) + 1)
+    r = rows[at]
+    d = S.euclidean_sq_batch(queries, tree.series(r))
+    words = jnp.stack([live[i][at] for i in range(live.shape[0])])
+    m = _unpack_queries(words, queries.shape[0]) & (slot < csum[-1])[None]
+    m &= ~jnp.any(r[None, :, None] == best_pos[:, None, :], axis=-1)
+    all_d = jnp.concatenate([best_d, jnp.where(m, d, jnp.inf)], axis=1)
+    all_p = jnp.concatenate([best_pos, jnp.where(m, r, -1)], axis=1)
+    _, sel = lax.top_k(-all_d, best_d.shape[1])
+    return (jnp.take_along_axis(all_d, sel, axis=1),
+            jnp.take_along_axis(all_p, sel, axis=1))
+
+
+@jax.jit
+def _wave_result(best_d, best_pos):
+    """The device pool as one array, for one read: ``[2, Qp, k]``
+    float32, the slots' bits in the second plane."""
+    return jnp.stack([best_d, lax.bitcast_convert_type(best_pos,
+                                                       jnp.float32)])
+
+
+def _pool_slots(best_off: np.ndarray, offs_all: np.ndarray,
+                idx0: np.ndarray) -> np.ndarray:
+    """The host pool's entries as :func:`wave_verify` holds them: the
+    row of this partition an entry names (only the seed window ``idx0``
+    can have put one there), else ``-2 - i`` for entry ``i``, and -1
+    for an empty slot."""
+    eq = offs_all[idx0][:, :, None] == best_off[:, None, :]  # [Q, C, k]
+    rows = np.take_along_axis(idx0, eq.argmax(axis=1), axis=1)
+    slots = np.where(eq.any(axis=1), rows, -2 - np.arange(best_off.shape[1]))
+    return np.where(best_off < 0, -1, slots).astype(np.int32)
+
+
+def _scan_waves(entry: ScanEntry, queries_j, q_paas_j, order: np.ndarray,
+                pool: KnnPool, stats: SearchStats, alive, offs_all,
+                idx0: np.ndarray, leaf_mark, union_mark, io) -> int:
+    """Scan a device partition's leaves ``order`` in waves, the pool on
+    the device.  Returns the live (query, row) pairs.
+
+    Wave ``j + 1``'s bound program is enqueued before wave ``j``'s
+    counts are read, so the device always holds work while the host
+    reads; it bounds against the pool as it stood before wave ``j``'s
+    verify, an older and looser bound, which only verifies more rows.
+    Each wave's verify merges before the next one's, so rows enter the
+    pool in visiting order, as in the host loop.  Leaves of waves the
+    stop flag ends count as pruned."""
+    part = entry.partition
+    tree = part.source
+    nq, qp, k = pool.nq, queries_j.shape[0], pool.k
+    leaf, n, seg_bytes = part.leaf_size, part.n, part.cfg.segments
+    row_bytes = part.cfg.series_len * 4
+    width, cap = _wave_shape(qp, part)
+    n_waves = -(-len(order) // width)
+    # the device arrays are sized for a scan of every leaf, so the shapes
+    # (and the compiled programs) do not depend on how many leaves the
+    # fence bounds pruned
+    n_slots = -(-part.n_leaves // width)
+    # the least bound of any leaf from each wave on: the stop test
+    lb_min = entry.leaf_bounds[:, order].min(axis=0)
+    rest = np.minimum.accumulate(lb_min[::-1])[::-1]
+    with _span("exec.launch"):
+        order_j = jnp.asarray(np.pad(order, (0, n_slots * width - len(order)),
+                                     mode="edge").astype(np.int32))
+        thresh_j = jnp.asarray(np.pad(rest[::width], (0, n_slots - n_waves),
+                                      mode="edge"))
+        # the padded queries' pools copy the last query's, as their rows do
+        best_d = jnp.asarray(_pad_batch(pool.best_d))
+        best_pos = jnp.asarray(_pad_batch(
+            _pool_slots(pool.best_off, offs_all, idx0)))
+        ext = jnp.asarray(_pad_batch(pool.ext[:, None])[:, 0])
+        alive_j = None if alive is None else jnp.asarray(alive)
+
+        def bound(j):
+            return wave_bound(tree.codes, q_paas_j, order_j, thresh_j, j,
+                              len(order), best_d, ext, alive_j,
+                              cfg=part.cfg, leaf_size=leaf, width=width)
+        nxt = bound(0)
+    stats.device_scans += 1
+    live_pairs = 0
+    for j in range(n_waves):
+        live, counts = nxt
+        if j + 1 < n_waves:
+            with _span("exec.launch"):
+                nxt = bound(j + 1)
+        counts = to_host(counts, stats)
+        stats.device_waves += 1
+        if counts[qp + 1, 0]:
+            stats.leaves_pruned += len(order) - j * width
+            break
+        leaves = order[j * width:(j + 1) * width]
+        per = counts[:nq, :len(leaves)]
+        union = counts[qp, :len(leaves)]
+        n_live = int(union.sum())
+        stats.leaves_scanned += len(leaves)
+        stats.scan_bytes += (int(np.minimum(leaf, n - leaves * leaf).sum())
+                             * seg_bytes + n_live * row_bytes)
+        if n_live == 0:
+            continue
+        t0 = time.perf_counter()
+        with _span("verify", rows=n_live) as vsp:
+            with _span("exec.launch"):
+                for b in range(-(-n_live // cap)):
+                    best_d, best_pos = wave_verify(
+                        tree, queries_j, order_j, j, live, b, best_d,
+                        best_pos, leaf_size=leaf, width=width, cap=cap)
+            vsp.set(candidates=n_live, raw_bytes=n_live * row_bytes)
+        stats.add_timing("verify", (time.perf_counter() - t0) * 1e3)
+        if io is not None:
+            io.seq_read(n_live)
+        stats.candidates += n_live
+        stats.candidates_per_query += per.sum(axis=1)
+        live_pairs += int(per.sum())
+        leaf_mark[:, leaves] |= per > 0
+        union_mark[leaves] |= union > 0
+    with _span("exec.launch"):
+        res = _wave_result(best_d, best_pos)
+    res = to_host(res, stats)[:, :nq]
+    with _span("exec.pool"):
+        d, pos = res[0], res[1].view(np.int32)
+        seeded = np.take_along_axis(pool.best_off,
+                                    np.clip(-2 - pos, 0, k - 1), axis=1)
+        off = np.where(pos >= 0, offs_all[np.maximum(pos, 0)], seeded)
+        pool.best_d = np.array(d, np.float32)
+        pool.best_off = np.where(pos == -1, -1, off).astype(np.int64)
+    return live_pairs
+
+
 def _scan_sorted(entry: ScanEntry, queries_j, q_paas_j, k: int,
                  pool: KnnPool, stats: SearchStats, *,
                  radius_leaves: int, chunk: int, io, mindist_fn,
@@ -302,9 +575,9 @@ def _scan_sorted(entry: ScanEntry, queries_j, q_paas_j, k: int,
     fused = scan_mode if part.backend == "device" else None
 
     with _span("seed", radius_leaves=radius_leaves):
-        alive, offs_all, _ = _seed_sorted(entry, queries_j, q_paas_j, pool,
-                                          stats, radius_leaves=radius_leaves,
-                                          io=io)
+        alive, offs_all, idx0 = _seed_sorted(
+            entry, queries_j, q_paas_j, pool, stats,
+            radius_leaves=radius_leaves, io=io)
 
     # -- leaf-granular pruning against the fence bounds --------------------
     # (the seed probe above always runs — the external bsf and the fence
@@ -320,7 +593,6 @@ def _scan_sorted(entry: ScanEntry, queries_j, q_paas_j, k: int,
         lb = entry.leaf_bounds                                # [Q, n_leaves]
         surv = np.nonzero((lb < bound[:, None]).any(axis=0))[0]
         stats.leaves_pruned += lb.shape[1] - len(surv)
-        stats.leaves_scanned += len(surv)
         psp.set(leaves_pruned=lb.shape[1] - len(surv),
                 leaves_surviving=len(surv))
         if len(surv) == 0:
@@ -330,17 +602,24 @@ def _scan_sorted(entry: ScanEntry, queries_j, q_paas_j, k: int,
         # cheapest leaves first: the bound tightens fastest, pruning the rest
         surv = surv[np.argsort(lb[:, surv].min(axis=0), kind="stable")]
 
-    leaves_per_grp = _leaves_per_group(chunk, nq, leaf)
+    groups = _leaf_groups(surv, _leaves_per_group(chunk, nq, leaf))
     leaf_mark = np.zeros((nq, lb.shape[1]), bool)
     union_mark = np.zeros(lb.shape[1], bool)
-    live_pairs = 0
-    for g in range(0, len(surv), leaves_per_grp):
-        grp = np.sort(surv[g:g + leaves_per_grp])    # sequential within grp
-        live, nbytes = _scan_leaf_group(
-            entry, queries_j, q_paas_j, grp, k, pool, stats, alive,
-            offs_all, leaf_mark, union_mark, io, mindist_fn, fused)
-        live_pairs += live
-        stats.scan_bytes += nbytes
+    if (part.backend == "device" and part.tiers is None and fused is None
+            and getattr(mindist_fn, "_coconut_default_mindist", False)):
+        live_pairs = _scan_waves(entry, queries_j, q_paas_j,
+                                 np.concatenate(groups), pool, stats,
+                                 alive, offs_all, idx0, leaf_mark,
+                                 union_mark, io)
+    else:
+        stats.leaves_scanned += len(surv)
+        live_pairs = 0
+        for grp in groups:
+            live, nbytes = _scan_leaf_group(
+                entry, queries_j, q_paas_j, grp, k, pool, stats, alive,
+                offs_all, leaf_mark, union_mark, io, mindist_fn, fused)
+            live_pairs += live
+            stats.scan_bytes += nbytes
     stats.leaves_touched += int(union_mark.sum())
     stats.leaves_per_query += leaf_mark.sum(axis=1)
     if label:

@@ -64,6 +64,10 @@ class SearchStats:
     device has computed it and copied it over, :func:`to_host`), and
     ``d2h_bytes`` the bytes those reads copied.  They count whether or
     not tracing is on.
+
+    Device scans: ``device_scans`` counts the sorted partitions scanned
+    in leaf waves with the pool on the device, ``device_waves`` the
+    waves whose counts were read (one device read each).
     """
     candidates: int = 0          # raw series whose true ED was computed
     pruned_frac: float = 0.0     # fraction of (query, row) pairs pruned
@@ -83,6 +87,8 @@ class SearchStats:
     budget_exhausted: bool = False   # drain stopped on the budget
     device_syncs: int = 0        # device results read back to the host
     d2h_bytes: int = 0           # bytes those reads copied
+    device_scans: int = 0        # partitions scanned in device leaf waves
+    device_waves: int = 0        # leaf waves whose counts were read
     gap: Optional[np.ndarray] = None          # [Q] certified epsilon bound
     lb_unvisited: Optional[np.ndarray] = None  # [Q] min unvisited-leaf lb
     # Observability riders (never affect answers): per-stage wall times
@@ -118,6 +124,8 @@ class SearchStats:
         self.scan_bytes += other.scan_bytes
         self.device_syncs += other.device_syncs
         self.d2h_bytes += other.d2h_bytes
+        self.device_scans += other.device_scans
+        self.device_waves += other.device_waves
         self.budget_exhausted = (self.budget_exhausted
                                  or other.budget_exhausted)
         for stage, ms in other.timings.items():

@@ -116,3 +116,40 @@ def test_mesh_scan_launch_compiles(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+
+
+@pytest.mark.parametrize("program", ["wave_bound", "wave_verify"])
+def test_wave_programs_fit_their_byte_cap(one_chip, program):
+    """The exact scan's two wave programs at a 2^22-row collection with
+    leaves of 2,000 rows and 16 queries: each compiles for one chip and
+    its intermediates stay under the executor's wave byte cap."""
+    from repro.core.tree import CoconutTree
+    from repro.query import Partition
+    from repro.query import executor as E
+    n, leaf, qp, L, w = 1 << 22, 2000, 16, CFG.series_len, CFG.segments
+    part = Partition(kind="tree", backend="device", cfg=CFG, n=n,
+                     leaf_size=leaf, source=None)
+    width, cap = E._wave_shape(qp, part)
+    order = _sds((-(-part.n_leaves // width) * width,), jnp.int32, one_chip)
+    best_d = _sds((qp, K), jnp.float32, one_chip)
+    if program == "wave_bound":
+        compiled = E.wave_bound.lower(
+            _sds((n, w), jnp.uint8, one_chip),
+            _sds((qp, w), jnp.float32, one_chip), order,
+            _sds((order.shape[0] // width,), jnp.float32, one_chip), 3,
+            part.n_leaves, best_d, _sds((qp,), jnp.float32, one_chip), None,
+            cfg=CFG, leaf_size=leaf, width=width).compile()
+    else:
+        tree = CoconutTree(
+            keys=_sds((n, CFG.n_words), jnp.uint32, one_chip),
+            codes=_sds((n, w), jnp.uint8, one_chip),
+            paas=_sds((n, w), jnp.float32, one_chip),
+            offsets=_sds((n,), jnp.int32, one_chip),
+            raw=_sds((n, L), jnp.float32, one_chip), raw_ref=None,
+            timestamps=None, cfg=CFG, leaf_size=leaf)
+        compiled = E.wave_verify.lower(
+            tree, _sds((qp, L), jnp.float32, one_chip), order, 3,
+            _sds((1, width * leaf), jnp.uint32, one_chip), 0, best_d,
+            _sds((qp, K), jnp.int32, one_chip),
+            leaf_size=leaf, width=width, cap=cap).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= E._WAVE_BYTES

@@ -119,8 +119,10 @@ def find_xplane(log_dir: str) -> str:
 
 
 MODULES_LINE = "XLA Modules"
-# the host event the TPU runtime records when a device program is done
+# the host event the TPU runtime records when a device program is done,
+# and its stat that names the device: core j is plane /device:TPU:j
 DONE_EVENT = "tpu::System::Execute=>Done"
+DONE_DEVICE_STAT = "core_id"
 
 
 def read_profile(path: str, marker: str, marker_perf: float) -> dict:
@@ -130,8 +132,11 @@ def read_profile(path: str, marker: str, marker_perf: float) -> dict:
     ``perf_counter`` read ``marker_perf``; it maps the trace's host
     clock onto ``perf_counter``.  Returns ``ops`` and ``modules``, the
     operations and the programs of each device plane as ``(name, start,
-    end)``; ``done``, the host's program-done events; and ``planes``,
-    every plane's name, for diagnosis."""
+    end)``; ``done``, the host's program-done events; ``done_by``, the
+    same split by the device plane each names (empty unless every one
+    names its device by :data:`DONE_DEVICE_STAT`);
+    ``done_stats``, the names of the stats the done events carry; and
+    ``planes``, every plane's name, for diagnosis."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     mark_ns: Optional[float] = None
@@ -139,6 +144,8 @@ def read_profile(path: str, marker: str, marker_perf: float) -> dict:
     ops: Dict[str, list] = {}
     mods: Dict[str, list] = {}
     done: List[float] = []
+    done_dev: List[Optional[int]] = []
+    done_stats = set()
     for plane in pd.planes:
         names.append(plane.name)
         device = plane.name.startswith(DEVICE_PLANE_PREFIX)
@@ -151,6 +158,10 @@ def read_profile(path: str, marker: str, marker_perf: float) -> dict:
                     mark_ns = ev.start_ns
                 elif ev.name == DONE_EVENT:
                     done.append(ev.start_ns)
+                    stats = dict(ev.stats)
+                    done_stats.update(stats)
+                    dev = stats.get(DONE_DEVICE_STAT)
+                    done_dev.append(None if dev is None else int(dev))
     if mark_ns is None:
         raise ValueError(f"marker {marker!r} not in the trace")
     off = marker_perf - mark_ns * 1e-9
@@ -158,8 +169,14 @@ def read_profile(path: str, marker: str, marker_perf: float) -> dict:
     def conv(d):
         return {p: [(n, s * 1e-9 + off, e * 1e-9 + off) for n, s, e in v]
                 for p, v in d.items()}
+    done_by: Dict[str, List[float]] = defaultdict(list)
+    if done and None not in done_dev:
+        for t, dev in zip(done, done_dev):
+            done_by[f"{DEVICE_PLANE_PREFIX}{dev}"].append(t * 1e-9 + off)
     return {"ops": conv(ops), "modules": conv(mods),
-            "done": sorted(t * 1e-9 + off for t in done), "planes": names}
+            "done": sorted(t * 1e-9 + off for t in done),
+            "done_by": {p: sorted(v) for p, v in done_by.items()},
+            "done_stats": sorted(done_stats), "planes": names}
 
 
 def device_lag(modules: Sequence[Tuple[str, float, float]],
@@ -175,9 +192,40 @@ def device_lag(modules: Sequence[Tuple[str, float, float]],
     return lags[len(lags) // 2]
 
 
+def plane_lags(modules: Dict[str, Sequence[Tuple[str, float, float]]],
+               done: Sequence[float],
+               done_by: Optional[Dict[str, Sequence[float]]] = None
+               ) -> Optional[Dict[str, float]]:
+    """The lag (:func:`device_lag`) of each device plane that ran a
+    program, by plane name, or None where it cannot be told.
+
+    One plane: its programs against every done event.  Several: each
+    plane against the done events that name its device, where every
+    plane's can be split so; otherwise one lag shared by all planes,
+    from every plane's programs together against every done event."""
+    planes = {p: m for p, m in modules.items() if m}
+    if len(planes) == 1:
+        (p, m), = planes.items()
+        lag = device_lag(m, done)
+        return None if lag is None else {p: lag}
+    if not planes:
+        return None
+    if done_by:
+        each = {p: device_lag(m, done_by.get(p, ()))
+                for p, m in planes.items()}
+        if None not in each.values():
+            return each
+    lag = device_lag([e for m in planes.values() for e in m], done)
+    return None if lag is None else dict.fromkeys(planes, lag)
+
+
 def shifted(planes: Dict[str, List[Tuple[str, float, float]]],
-            by: float) -> Dict[str, List[Tuple[str, float, float]]]:
-    return {p: [(n, s + by, e + by) for n, s, e in v]
+            lags: Dict[str, float]
+            ) -> Dict[str, List[Tuple[str, float, float]]]:
+    """Every plane's intervals moved by its lag in ``lags`` (a plane
+    without one, which ran no program, stays)."""
+    return {p: [(n, s + lags.get(p, 0.0), e + lags.get(p, 0.0))
+                for n, s, e in v]
             for p, v in planes.items()}
 
 

@@ -1,11 +1,14 @@
 """One run of one cell: set-up, measured window, check, result line.
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
-its configuration (``configs/<config>.json``), its traffic mix
-(``traffic/<mix>.json``, run by :mod:`loops`) and every metric it
-reports (``metrics/<metric>.py``, a ``read(run)`` that returns a number
-or None when it finds nothing to read).  Adding a cell, a mix or a
-metric therefore means adding files and entries, not editing these.
+its configuration (``configs/<config>.json``), whose ``"kind"`` names
+the system under test and its control (``systems/<kind>.py``); its
+traffic mix (``traffic/<mix>.json``), whose ``"loop"`` names the loop
+that runs it (``loops/<loop>.py``); and every metric it reports
+(``metrics/<metric>.py``, a ``read(run)`` that returns a number or None
+when it finds nothing to read).  Adding a cell, a mix, a metric, a
+configuration kind or a loop therefore means adding files and entries,
+not editing these.
 """
 from __future__ import annotations
 
@@ -67,7 +70,7 @@ def reader(name: str):
 class RunView:
     """What a metric reader sees of one run.  Times are seconds on
     ``time.perf_counter``'s clock."""
-    kind: str                       # the mix's loop: probe, ingest, build
+    kind: str                       # the loop's kind: probe, ingest, build
     config: dict
     traffic: dict
     calls: List[dict]               # one record per call of the window
@@ -151,7 +154,9 @@ def _traced_window(loop, seconds: float):
     """The window under the profiler, with the program's spans on.
     Returns (host spans as ``(name, start, end, thread)``, device
     operations and programs by plane, the device clock's lag behind the
-    host's or None), all on ``perf_counter``'s clock."""
+    host's where one plane ran programs (else None), and what the
+    profile held, each plane's lag with it), all on ``perf_counter``'s
+    clock."""
     import jax
     import devtrace
     import system as sysmod
@@ -172,17 +177,22 @@ def _traced_window(loop, seconds: float):
     if not any(ops.values()):
         raise RuntimeError(f"no device operations in the trace; planes: "
                            f"{prof['planes']}")
-    # one chip: its programs' ends line up with the host's program-done
-    # events, which puts the device on the host's clock
+    # the programs' ends line up with the host's program-done events,
+    # which puts each device plane on the host's clock
+    lags = devtrace.plane_lags(mods, prof["done"], prof["done_by"])
     lag = None
-    if len(mods) == 1:
-        lag = devtrace.device_lag(next(iter(mods.values())), prof["done"])
-    if lag is not None:
-        ops, mods = devtrace.shifted(ops, lag), devtrace.shifted(mods, lag)
+    if lags is not None:
+        ops, mods = devtrace.shifted(ops, lags), devtrace.shifted(mods, lags)
+        if len(lags) == 1:
+            lag, = lags.values()
+    held = {"programs": {p: len(m) for p, m in mods.items()},
+            "done": len(prof["done"]),
+            "done_by": {p: len(v) for p, v in prof["done_by"].items()},
+            "done_stats": prof["done_stats"], "lags": lags}
     spans = [(s["name"], epoch + s["ts"] * 1e-6,
               epoch + (s["ts"] + s["dur"]) * 1e-6, s["tid"])
              for s in raw_spans]
-    return spans + ann.spans, ops, mods, lag
+    return spans + ann.spans, ops, mods, lag, held
 
 
 def run_cell(bench: dict, workload: str, seed: int, seconds: float,
@@ -196,24 +206,23 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     import jax
     import loops
     import system as sysmod
+    import systems
     _, _, config, traffic = cell_of(bench, workload)
-    kind = traffic["loop"]
     if system is None:
-        system = (sysmod.StaticIndex(config)
-                  if config["kind"] == "static_collection"
-                  else sysmod.StreamIndex(config))
+        system = systems.of(config["kind"]).system(config)
     workdir = WORK / workload
     shutil.rmtree(workdir, ignore_errors=True)
-    loop = loops.LOOPS[kind](config, traffic, seed, system,
-                             workdir=workdir)
+    loop = loops.of(traffic["loop"])(config, traffic, seed, system,
+                                     workdir=workdir)
+    kind = loop.kind or traffic["loop"]
     try:
         loop.setup()
         setup_s = time.perf_counter() - t_start
         before = sysmod.registry_snapshot()
         c0 = clock.read() if clock is not None else (0.0, 0, 0)
-        spans = ops = mods = lag = None
+        spans = ops = mods = lag = held = None
         if trace:
-            spans, ops, mods, lag = _traced_window(loop, seconds)
+            spans, ops, mods, lag, held = _traced_window(loop, seconds)
         else:
             loop.window(seconds)
         c1 = clock.read() if clock is not None else (0.0, 0, 0)
@@ -255,14 +264,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             "errors": loop.errors[:3], "setup": loop.phases,
             "check_s": check_s, "setup_compiles": c0[1],
             "setup_compile_s": c0[0], "cache_hits": c1[2]}
-    if kind == "ingest":
-        acks = [a for c in loop.calls for a in c.get("acks", ())]
-        info["insert_s"] = sum(b - a for a, b, _ in acks)
-        info["counters"] = {k: v for k, v in counters.items()
-                            if k.startswith(("compact.", "ingest.wal",
-                                             "ingest.backpressure",
-                                             "io.bytes"))
-                            and not k.endswith(("p50", "p95", "p99"))}
+    info.update(loop.info(counters))
     if trace:
         import devtrace
         lo, hi = view.window
@@ -278,6 +280,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
             "idle_gaps": devtrace.idle_by_host(allops, host, lo, hi)}
         info["device_lag_s"] = lag
         info["device_programs"] = len(progs)
+        info["trace"] = held
     out["checks"] = checks
     return out, info
 

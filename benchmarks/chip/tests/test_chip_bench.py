@@ -28,6 +28,7 @@ sys.path.insert(0, str(ROOT / "src"))
 import devtrace  # noqa: E402
 import harness  # noqa: E402
 import roofline  # noqa: E402
+import systems  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -62,7 +63,95 @@ def test_device_lag_from_program_done_events():
     assert devtrace.device_lag(mods, [1.2, 3.25, 5.7]) == pytest.approx(
         0.2)
     assert devtrace.device_lag(mods, [1.2]) is None
-    assert devtrace.shifted({"a": mods}, 0.5)["a"][0] == ("p", 0.5, 1.5)
+    assert devtrace.shifted({"a": mods}, {"a": 0.5})["a"][0] == (
+        "p", 0.5, 1.5)
+
+
+def _profile(path, planes, runs, lags, device_stat=False):
+    """A recorded trace of ``planes`` device planes, each running
+    ``runs`` programs of 2 ms (plane ``j`` 0.3 ms behind plane 0), whose
+    clock runs ``lags[j]`` seconds behind the host's.  The host records
+    a marker at 1 ms and a program-done event for each program, naming
+    its device by ``core_id`` where ``device_stat``.  Returns the
+    ``perf_counter`` reading of the marker."""
+    from jax.profiler import ProfileData
+    ps = 1000                                # picoseconds a nanosecond
+    start = [[(10_000_000 + 5_000_000 * i + 300_000 * j)
+              for i in range(runs)] for j in range(planes)]
+    host = ['events { metadata_id: 1 offset_ps: %d duration_ps: %d }'
+            % (1_000_000 * ps, 1000 * ps)]
+    for j in range(planes):
+        for s in start[j]:
+            ns = s + 2_000_000 + round(lags[j] * 1e9)
+            stat = ('stats { metadata_id: 1 int64_value: %d }' % j
+                    if device_stat else '')
+            host.append('events { metadata_id: 2 offset_ps: %d '
+                        'duration_ps: %d %s }' % (ns * ps, 1000 * ps, stat))
+    txt = ['planes { id: 1 name: "/host:CPU" lines { id: 1 name: "python"'
+           ' timestamp_ns: 0 %s }' % " ".join(host),
+           'event_metadata { key: 1 value { id: 1 name: "bench.mark" } }',
+           'event_metadata { key: 2 value { id: 2 name: "%s" } }'
+           % devtrace.DONE_EVENT,
+           'stat_metadata { key: 1 value { id: 1 name: "%s" } } }'
+           % devtrace.DONE_DEVICE_STAT]
+    for j in range(planes):
+        evs = " ".join('events { metadata_id: 1 offset_ps: %d '
+                       'duration_ps: %d }' % (s * ps, 2_000_000 * ps)
+                       for s in start[j])
+        txt.append(
+            'planes { id: %d name: "%s%d" lines { id: 1 name: "%s" '
+            'timestamp_ns: 0 %s } lines { id: 2 name: "%s" timestamp_ns: 0'
+            ' %s } event_metadata { key: 1 value { id: 1 name: '
+            '"jit_step(1)" } } }' % (j + 2, devtrace.DEVICE_PLANE_PREFIX, j,
+                                     devtrace.MODULES_LINE, evs,
+                                     devtrace.OPS_LINE, evs))
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(
+        "\n".join(txt)))
+    return 100.0
+
+
+@pytest.mark.parametrize("planes,device_stat", [
+    (1, False), (1, True), (4, False), (4, True)])
+def test_device_planes_put_on_the_host_clock(tmp_path, planes,
+                                             device_stat):
+    """A synthetic profile whose device clocks run a known lag behind the
+    host's: one plane keeps the lag of :func:`devtrace.device_lag` bit
+    for bit; several get a lag each where the done events name their
+    device, and one shared lag where they do not."""
+    lags = ([0.0013, 0.0021, 0.0008, 0.0017][:planes] if device_stat
+            else [0.0013] * planes)
+    path = tmp_path / "t.xplane.pb"
+    mark = _profile(path, planes, 6, lags, device_stat)
+    prof = devtrace.read_profile(str(path), "bench.mark", mark)
+    mods = prof["modules"]
+    assert len(mods) == planes and all(len(m) == 6 for m in mods.values())
+    assert bool(prof["done_by"]) == device_stat
+    got = devtrace.plane_lags(mods, prof["done"], prof["done_by"])
+    names = [f"{devtrace.DEVICE_PLANE_PREFIX}{j}" for j in range(planes)]
+    assert sorted(got) == names
+    if planes == 1:
+        assert got[names[0]] == devtrace.device_lag(mods[names[0]],
+                                                   prof["done"])
+    if not device_stat:                      # one lag, shared by all
+        assert len(set(got.values())) == 1
+    for name, lag in zip(names, lags):
+        assert got[name] == pytest.approx(lag, abs=1e-9)
+    ops = devtrace.shifted(prof["ops"], got)
+    for name in names:
+        for (_, s, e), (_, s0, e0) in zip(ops[name], prof["ops"][name]):
+            assert (s - s0, e - e0) == pytest.approx((got[name],
+                                                      got[name]))
+    ends = sorted(e for m in devtrace.shifted(mods, got).values()
+                  for _, _, e in m)           # every end now on its done
+    assert ends == pytest.approx(prof["done"], abs=1e-9)
+
+
+def test_no_lag_without_a_done_event_for_each_program():
+    mods = {"a": [("p", 0.0, 1.0), ("p", 2.0, 3.0)],
+            "b": [("p", 0.5, 1.5)]}
+    assert devtrace.plane_lags(mods, [1.2], {}) is None
+    assert devtrace.plane_lags({"a": []}, [1.2], {}) is None
+    assert devtrace.plane_lags({"a": mods["a"]}, [1.2], {}) is None
 
 
 def test_idle_gaps_named_by_the_innermost_host_span():
@@ -168,25 +257,41 @@ def test_benchmark_json_keys_names_and_units():
     for m in BENCH["per_layer"]:
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    assert [w["chips"] for w in BENCH["workloads"]] == [1] * 4
+    chips = [w["chips"] for w in BENCH["workloads"]]
+    assert set(chips) <= {1, 4}
+    assert chips.count(4) <= max(1, len(chips) // 2)
     pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
     assert len(pairs) == len(set(pairs))
 
 
 def test_every_name_resolves_to_a_file():
+    import loops
     for c in BENCH["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert c["file"].startswith("benchmarks/chip/configs/")
         assert set(c["reduced"]) <= set(cfg), c["name"]
-        assert cfg["kind"] in ("static_collection", "streaming_lsm")
+        assert cfg["kind"] in systems.found()
+        kind = systems.of(cfg["kind"])
+        for part in ("system", "control", "cpu_size"):
+            assert callable(getattr(kind, part)), (cfg["kind"], part)
     for w in BENCH["workloads"]:
         assert w["config"] in {c["name"] for c in BENCH["configs"]}
         traffic = json.loads((HERE / "traffic" /
                               f"{w['traffic']}.json").read_text())
-        import loops
-        assert traffic["loop"] in loops.LOOPS
+        assert traffic["loop"] in loops.found()
+        assert issubclass(loops.of(traffic["loop"]), loops.Loop)
     for m in BENCH["end_to_end"] + BENCH["per_layer"]:
         assert callable(harness.reader(m["name"])), m["name"]
+
+
+def test_unknown_kind_and_loop_name_what_is_found():
+    import loops
+    with pytest.raises(SystemExit, match="static_collection"):
+        systems.of("no_such_kind")
+    with pytest.raises(SystemExit, match="probe"):
+        loops.of("no_such_loop")
+    assert {"static_collection", "streaming_lsm"} <= set(systems.found())
+    assert {"probe", "ingest", "build"} <= set(loops.found())
 
 
 def test_each_cell_reports_what_its_metrics_need():
@@ -212,18 +317,10 @@ _CELL_OF = harness.cell_of
 
 
 def tiny(workload):
-    """The cell's configuration and mix at a size a CPU test holds."""
+    """The cell's configuration and mix at a size a CPU test holds, as
+    the configuration kind's file cuts them."""
     cell, entry, config, traffic = _CELL_OF(BENCH, workload)
-    config, traffic = dict(config), dict(traffic)
-    if config["kind"] == "static_collection":
-        config.update(series=1 << 13, leaf_size=256, make_block=2048)
-    else:
-        config.update(rows_per_round=1 << 13, buffer_capacity=3 * 2048,
-                      leaf_size=256)
-        traffic.update(batch=2048, readback_rows=64)
-    if traffic["loop"] == "probe":
-        traffic.update(pool_calls=min(traffic["pool_calls"], 8),
-                       warmup_calls=2)
+    config, traffic = systems.of(config["kind"]).cpu_size(config, traffic)
     return cell, entry, config, traffic
 
 
@@ -241,6 +338,8 @@ def run(workload, system=None, trace=False, seed=2 ** 33 + 5):
 
 
 CELLS = [w["name"] for w in BENCH["workloads"]]
+STATIC = systems.of("static_collection")
+STREAM = systems.of("streaming_lsm")
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -261,12 +360,12 @@ def test_probe_seeds_run_one_pool_in_another_order(tmp_path):
     """With a ``data_key`` every seed probes the same collection with the
     same queries, in an order of its own."""
     import loops
-    import system as sysmod
     _, _, config, traffic = tiny("rand4m-approx-q1")
     pools = []
     for seed in (1, 2 ** 40 + 1):
-        lp = loops.ProbeLoop(config, traffic, seed,
-                             sysmod.StaticIndex(config), workdir=tmp_path)
+        lp = loops.of("probe")(config, traffic, seed,
+                               STATIC.StaticIndex(config),
+                               workdir=tmp_path)
         lp.setup()
         pools.append(lp.pool.reshape(-1, lp.pool.shape[-1]))
     a, b = pools
@@ -277,18 +376,17 @@ def test_probe_seeds_run_one_pool_in_another_order(tmp_path):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_control_fails_the_check(tiny_cells, workload):
-    import control
     _, _, config, traffic = tiny(workload)
-    out, _ = run(workload, control.control_for(config, traffic))
+    out, _ = run(workload, systems.of(config["kind"]).control(config,
+                                                              traffic))
     assert out["failed"] == 0            # refused by the check, not a crash
     assert not out["correct"], out["checks"]
 
 
 # --------------------------------------------------- planted faults
-import system as sysmod  # noqa: E402
 
 
-class HalfBatch(sysmod.StaticIndex):
+class HalfBatch(STATIC.StaticIndex):
     """Answers the first half of the batch and copies it over the rest."""
 
     def search(self, tree, queries, *, k, budget):
@@ -301,7 +399,7 @@ class HalfBatch(sysmod.StaticIndex):
         return cut(d), cut(ids), c, cut(gap)
 
 
-class AlteredAnswer(sysmod.StaticIndex):
+class AlteredAnswer(STATIC.StaticIndex):
     def search(self, tree, queries, *, k, budget):
         d, ids, c, gap = super().search(tree, queries, k=k, budget=budget)
         ids = ids.copy()
@@ -309,7 +407,7 @@ class AlteredAnswer(sysmod.StaticIndex):
         return d, ids, c, gap
 
 
-class Unchanged(sysmod.StaticIndex):
+class Unchanged(STATIC.StaticIndex):
     """A search that returns its pool as it started: nothing found."""
 
     def search(self, tree, queries, *, k, budget):
@@ -318,7 +416,7 @@ class Unchanged(sysmod.StaticIndex):
                 None if gap is None else np.zeros_like(gap))
 
 
-class UnsortedBuild(sysmod.StaticIndex):
+class UnsortedBuild(STATIC.StaticIndex):
     """A build that leaves the rows in their arrival order."""
 
     def build(self, raw):
@@ -331,7 +429,7 @@ class UnsortedBuild(sysmod.StaticIndex):
             offsets=jnp.arange(tree.n, dtype=jnp.int32))
 
 
-class AlteredRow(sysmod.StaticIndex):
+class AlteredRow(STATIC.StaticIndex):
     def build(self, raw):
         tree = super().build(raw)
         return dataclasses.replace(tree, raw=tree.raw.at[3, 7].add(1e-3))
@@ -349,14 +447,14 @@ class _Writer:
         return getattr(self.eng, name)
 
 
-class DroppedInserts(sysmod.StreamIndex):
+class DroppedInserts(STREAM.StreamIndex):
     """Acknowledges every insert and keeps none: the state unchanged."""
 
     def create(self, root):
         return _Writer(super().create(root), drop=True)
 
 
-class AlteredRows(sysmod.StreamIndex):
+class AlteredRows(STREAM.StreamIndex):
     def create(self, root):
         def alter(r):
             r = np.array(r)
@@ -365,7 +463,7 @@ class AlteredRows(sysmod.StreamIndex):
         return _Writer(super().create(root), rows=alter)
 
 
-class UnloggedInserts(sysmod.StreamIndex):
+class UnloggedInserts(STREAM.StreamIndex):
     """Acknowledges inserts that never reach the write-ahead log."""
 
     def create(self, root):

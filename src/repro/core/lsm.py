@@ -39,17 +39,22 @@ serving layer (:mod:`repro.distributed.sharded_lsm`) builds on that plus
 a few hooks here: ``insert(ids=, key_fence=)`` for router-assigned ids
 and z-order fences, per-run/snapshot key fences (whole-shard pruning),
 ``search_exact*(bsf=)`` external bounds (cross-shard best-so-far
-chaining), ``advance_clock`` (one window clock across shards), and
-``debt_cv`` (a shared backpressure budget the compactor pokes).
+chaining), ``advance_clock`` (one window clock across shards),
+``debt_cv`` (a shared backpressure budget the compactor pokes),
+``device`` (the chip every array of the engine's runs lives on) and
+``load_run`` (a run bulk-loaded on that chip).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 import os
 import threading
 import time
 from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -203,6 +208,10 @@ class CoconutLSM:
         # backpressure budget on it (see ShardedCoconutLSM.insert)
         self.debt_cv: Optional[threading.Condition] = None
         self.ingest = IngestMetrics()
+        # the device every array of every run lives on: flushes and
+        # merges run there and their trees are committed to it.  None
+        # (the default): wherever JAX puts them, the default device
+        self.device = None
         self.wal = None
         if store is not None:
             from ..ingest.wal import WriteAheadLog
@@ -555,9 +564,24 @@ class CoconutLSM:
             self._flushing.append(entry)
             return entry
 
+    def _on_device(self):
+        """Context placing new arrays on the engine's device (thread
+        local, so a compactor thread's flush lands there too)."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
+
+    def _placed(self, tree: T.CoconutTree) -> T.CoconutTree:
+        """``tree`` committed to the engine's device: no copy where its
+        arrays already live there, a copy of each where they do not (a
+        run reopened from its segment on the default device)."""
+        if self.device is None:
+            return tree
+        return jax.device_put(tree, self.device)
+
     def _build_run(self, entry: _PendingFlush) -> Run:
         t0 = time.perf_counter()
-        with _span("compact.flush", rows=entry.n):
+        with _span("compact.flush", rows=entry.n), self._on_device():
             head_raw = np.concatenate(entry.raw_parts)
             head_ts = np.concatenate(entry.ts_parts)
             head_ids = np.concatenate(entry.id_parts)
@@ -566,12 +590,11 @@ class CoconutLSM:
                                        for s in entry.sum_parts):
                 paas = np.concatenate([s[0] for s in entry.sum_parts])
                 codes = np.concatenate([s[1] for s in entry.sum_parts])
-            tree = T.build(jnp.asarray(head_raw), self.cfg,
-                           leaf_size=self.leaf_size,
-                           materialized=self.materialized,
-                           timestamps=jnp.asarray(head_ts),
-                           ids=head_ids,
-                           io=self.io, paas=paas, codes=codes)
+            tree = self._placed(T.build(
+                jnp.asarray(head_raw), self.cfg, leaf_size=self.leaf_size,
+                materialized=self.materialized,
+                timestamps=jnp.asarray(head_ts), ids=head_ids, io=self.io,
+                paas=paas, codes=codes))
         reg = get_registry()
         reg.histogram("compact.flush_ms").observe(
             (time.perf_counter() - t0) * 1e3)
@@ -586,8 +609,9 @@ class CoconutLSM:
         whatever first reads the merged run (``segment.fetch`` at the
         commit)."""
         with _span("compact.merge", rows=a.n + b.n,
-                   level_a=a.level, level_b=b.level):
-            return T.merge_trees(a.tree, b.tree, io=self.io)
+                   level_a=a.level, level_b=b.level), self._on_device():
+            return T.merge_trees(self._placed(a.tree),
+                                 self._placed(b.tree), io=self.io)
 
     def _publish_run(self, entry, run: Run) -> None:
         """Atomically swap the flushed head out of the buffer view and the
@@ -598,6 +622,35 @@ class CoconutLSM:
             self.data_epoch += 1
             self._dirty = True
             self._cv.notify_all()
+
+    def load_run(self, tree: T.CoconutTree) -> None:
+        """Publish ``tree``, built elsewhere from rows that never entered
+        this engine's buffer (a bulk load), as the only run of an empty
+        engine: committed to the engine's device, at the level its size
+        would have reached by flushes and merges, with the clock and the
+        local insert count advanced past it as inserting its rows would.
+        The tree must carry global ids and timestamps."""
+        if tree.ids is None or tree.timestamps is None:
+            raise ValueError("a loaded run needs ids and timestamps")
+        tree = self._placed(tree)
+        t_min = int(jnp.min(tree.timestamps))
+        t_max = int(jnp.max(tree.timestamps))
+        level = 0
+        if tree.n > self.buffer_capacity and self.size_ratio > 1:
+            level = int(math.log(tree.n / self.buffer_capacity,
+                                 self.size_ratio))
+        with self._cv:
+            if self.runs or self._buf_count or self._flushing:
+                raise ValueError("load_run needs an empty engine")
+            self.runs.insert(0, Run(tree=tree, level=level, t_min=t_min,
+                                    t_max=t_max))
+            self._rows_inserted += tree.n
+            self.clock = max(self.clock, t_max + 1)
+            self.data_epoch += 1
+            self._dirty = True
+            self._cv.notify_all()
+        if self.store is not None and not self.concurrent:
+            self._commit()
 
     def _merge_plan_locked(self) -> Optional[Tuple[Run, Run]]:
         """Next pair to merge under the leveling policy, or None.

@@ -22,13 +22,17 @@ The router owns the keyspace partition of a :class:`ShardedCoconutLSM`:
     whose bound cannot beat the best-so-far chain is skipped whole:
     no code scan, no raw fetch.
 
-Everything here is host-side numpy: routing runs on the insert path
-(where batches are numpy already) and fence bounds are O(w) per shard.
+Routing is one device program (:func:`route_keys`), run on the insert
+path's numpy batches and on a bulk load's device-resident keys alike;
+everything else here is host-side numpy (fence bounds are O(w) per
+shard).
 """
 from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core import keys as K
@@ -36,7 +40,21 @@ from ..core import summarization as S
 from .samplesort import splitters_from_sample
 
 __all__ = ["KeyRangeRouter", "fence_mindist_sq", "key_range_code_bounds",
-           "batch_keys", "batch_summaries", "key_fence_of"]
+           "batch_keys", "batch_summaries", "key_fence_of", "route_keys"]
+
+
+@jax.jit
+def route_keys(boundaries: jax.Array, keys: jax.Array) -> jax.Array:
+    """Destination shard of each key ``[n, n_words]``: the number of
+    splitters ``[S-1, n_words]`` not above it, which is
+    ``searchsorted(splitters, key, side="right")`` (the sample-sort's
+    bucketing) with one compare of the batch per splitter, where a
+    binary search gathers a splitter for every key.  Runs where the
+    keys live."""
+    dest = jnp.zeros(keys.shape[0], jnp.int32)
+    for b in range(boundaries.shape[0]):
+        dest += K.key_less_equal(boundaries[b], keys).astype(jnp.int32)
+    return dest
 
 
 def batch_summaries(raw: np.ndarray, cfg: S.SummaryConfig
@@ -190,14 +208,11 @@ class KeyRangeRouter:
 
     # --------------------------------------------------------------- routing
     def route(self, keys: np.ndarray) -> np.ndarray:
-        """Destination shard per key — ``searchsorted(splitters, key,
-        side="right")``, bit-matching the sample-sort's bucketing."""
+        """Destination shard per key (:func:`route_keys`), as numpy."""
         if self.n_shards == 1 or self.boundaries is None:
             return np.zeros(len(keys), np.int64)
-        import jax.numpy as jnp
-        dest = K.searchsorted_keys(jnp.asarray(self.boundaries),
-                                   jnp.asarray(keys), side="right")
-        return np.asarray(dest, np.int64)
+        return np.asarray(route_keys(jnp.asarray(self.boundaries),
+                                     jnp.asarray(keys)), np.int64)
 
     # --------------------------------------------------------- serialization
     def boundaries_json(self) -> Optional[List[List[int]]]:

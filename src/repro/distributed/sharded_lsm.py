@@ -25,7 +25,16 @@ shards partitioned by z-order key range, behind a router that
   * **rebalances** under skew: sampled keys re-estimate the splitters,
     and a split/merge migration rebuilds the shard set (new generation
     of shard dirs, atomically committed) with ids/timestamps preserved,
-    so answers are unchanged by the move.
+    so answers are unchanged by the move;
+  * **places** shard ``i`` on its device (``launch.mesh.shard_device``,
+    the rule the scan mesh is built by): every array of its runs lives
+    there, so a collection larger than one chip spreads over the chips
+    and the mesh scan pins each shard where its rows already are;
+  * **bulk-loads** a collection made on the devices
+    (:meth:`ShardedCoconutLSM.bulk_load`): summarized where it lives,
+    routed by the same splitters, each shard's rows copied device to
+    device and built into one run on its chip, with no row passing
+    through the host.
 
 Exactness composes across shards for the same reason it composes across
 runs and the frozen buffer (see ``ingest/snapshot.py``): exact distances
@@ -43,12 +52,15 @@ after ``flush()`` (when everything is visible), not mid-buffer.
 """
 from __future__ import annotations
 
+import contextvars
+import functools
 import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -59,9 +71,19 @@ from ..core.metrics import IngestMetrics, IOStats
 from ..obs import get_registry, probe, span as _span
 from ..query.merger import merge_pools
 from .router import (KeyRangeRouter, batch_summaries, fence_mindist_sq,
-                     key_fence_of, key_range_code_bounds)
+                     key_fence_of, key_range_code_bounds, route_keys)
 
 __all__ = ["ShardedCoconutLSM"]
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def _route_slice(dest: jax.Array, shard: jax.Array, base: jax.Array,
+                 raw: jax.Array, paas: jax.Array, codes: jax.Array, *,
+                 size: int):
+    """The ``size`` rows of a block bound for ``shard``, in block order:
+    (rows, paas, codes, global ids ``base + row``)."""
+    idx = jnp.nonzero(dest == shard, size=size)[0].astype(jnp.int32)
+    return raw[idx], paas[idx], codes[idx], base + idx
 
 
 class _AggregateIngest:
@@ -201,6 +223,7 @@ class ShardedCoconutLSM:
         # use it to capture an atomic multi-shard snapshot set
         self._epoch = 0
         self._since_rebalance = 0
+        self._placement_done = False        # shards put on their devices
         self.metrics = IngestMetrics()        # router-level counters
         self.ingest = _AggregateIngest(self)
         # fan-out pool: per-shard sub-batch inserts are independent
@@ -273,6 +296,7 @@ class ShardedCoconutLSM:
                          tiers=tiers, scan_mode=scan_mode)
         for e in engines:
             e.advance_clock(clock)
+        obj._place()
         return obj
 
     def _commit_meta(self) -> None:
@@ -303,6 +327,21 @@ class ShardedCoconutLSM:
         with self._state_lock:
             return list(self._shards)
 
+    def _place(self, shards: Optional[List[CoconutLSM]] = None) -> None:
+        """Put shard ``i`` on its device (``launch.mesh.shard_device``):
+        its flushes and merges run there and every array of its runs
+        lives there.  Nothing moves where the scan mesh spans one
+        device.  Done at the first write rather than at construction,
+        so building an engine touches no device state."""
+        from ..launch.mesh import scan_devices, shard_device
+        if shards is None:
+            if self._placement_done:
+                return
+            shards, self._placement_done = self._shard_list(), True
+        if len(scan_devices(len(shards))) > 1:
+            for i, s in enumerate(shards):
+                s.device = shard_device(i, len(shards))
+
     def insert(self, raw: np.ndarray,
                timestamps: Optional[np.ndarray] = None) -> None:
         """Route one insert batch to its key-range shards.
@@ -319,6 +358,7 @@ class ShardedCoconutLSM:
         if n == 0:
             return
         with self._mutex:
+            self._place()
             with self._state_lock:
                 if timestamps is None:
                     timestamps = np.arange(self.clock, self.clock + n,
@@ -378,6 +418,139 @@ class ShardedCoconutLSM:
                 and self._since_rebalance >= self.rebalance_every):
             self._since_rebalance = 0
             self.rebalance()
+
+    def bulk_load(self, blocks: Sequence[jax.Array]) -> None:
+        """Load a collection made on the devices into this empty engine,
+        with no row passing through the host.
+
+        ``blocks`` holds the collection as one ``[n_j, L]`` float32
+        device array per chip; row ``r`` of block ``j`` gets the global
+        id and timestamp ``n_0 + ... + n_{j-1} + r``.  Each block is
+        summarized on its own device; the router's boundaries are set
+        from an even sample of the keys by the splitter rule ``insert``
+        uses (and committed before any run is published); the rows each
+        shard owns are copied from every block to the shard's device,
+        one destination slice at a time, and a block is deleted once
+        all its slices are sent (the engine consumes the blocks); each
+        shard builds its one run there (``tree.build`` with the routed
+        summaries and ids) and publishes it (``CoconutLSM.load_run``).
+        The clock, the id allocator and the key fences then stand where
+        inserting the same rows would have left them.
+
+        Traced as ``shard.load`` over one ``shard.route``, one
+        ``shard.exchange`` per block (attribute ``bytes``: what it sent
+        to other devices, also counted by ``shard.exchange_bytes_total``)
+        and one ``shard.build`` per shard (attribute ``shard``).
+        """
+        self._check_open()
+        blocks = list(blocks)
+        total = sum(int(b.shape[0]) for b in blocks)
+        reg = get_registry()
+        with self._mutex, _span("shard.load", rows=total,
+                                blocks=len(blocks), shards=self.n_shards):
+            with self._state_lock:
+                if self._next_id or any(s.n for s in self._shards):
+                    raise ValueError("bulk_load needs an empty engine")
+            self._place()
+            shards = self._shard_list()
+            n_sh = len(shards)
+            with _span("shard.route", rows=total):
+                # commit each block where it was made (no copy) so every
+                # program of the load runs on the block's own device
+                blocks = [jax.device_put(b, next(iter(b.devices())))
+                          for b in blocks]
+                sums = []
+                for b in blocks:
+                    paas, codes = S.summarize(b, self.cfg)
+                    sums.append((paas, codes, S.invsax_keys(codes,
+                                                            self.cfg)))
+                per = max(1, self.router.sample_cap // len(blocks))
+                sample = np.concatenate([
+                    np.asarray(keys[::max(1, keys.shape[0] // per)])
+                    for _, _, keys in sums])
+                if self.router.ensure_boundaries(sample):
+                    self._commit_meta()   # boundaries durable first
+                self.router.observe(sample)
+                bounds = (np.zeros((0, self.cfg.n_words), np.uint32)
+                          if self.router.boundaries is None
+                          else self.router.boundaries)
+                dests = [route_keys(jnp.asarray(bounds), keys)
+                         for _, _, keys in sums]
+                shard_ids = jnp.arange(n_sh, dtype=jnp.int32)[:, None]
+                counts = np.stack([np.asarray(jnp.sum(d[None] == shard_ids,
+                                                      axis=1))
+                                   for d in dests])
+            # an unplaced shard's runs live on the default device
+            devs = [s.device or jax.devices()[0] for s in shards]
+            pieces: List[list] = [[] for _ in range(n_sh)]
+            base = 0
+            for j, b in enumerate(blocks):
+                src = next(iter(b.devices()))
+                paas, codes, _ = sums[j]
+                dest = dests[j]
+                sent = 0
+                with _span("shard.exchange", block=j,
+                           rows=int(b.shape[0])) as sp:
+                    for i in range(n_sh):
+                        c = int(counts[j, i])
+                        if c == 0:
+                            continue
+                        part = _route_slice(dest, jnp.int32(i),
+                                            jnp.int32(base), b, paas,
+                                            codes, size=c)
+                        if devs[i] != src:
+                            part = jax.device_put(part, devs[i])
+                            sent += sum(int(x.nbytes) for x in part)
+                        pieces[i].append(jax.block_until_ready(part))
+                    sp.set(bytes=sent)
+                reg.counter("shard.exchange_bytes_total").inc(sent)
+                base += int(b.shape[0])
+                b.delete()
+                blocks[j] = sums[j] = dests[j] = None
+                del paas, codes, dest
+
+            def build(i: int) -> None:
+                parts, pieces[i] = pieces[i], None
+                if not parts:
+                    return
+                sh = shards[i]
+                with _span("shard.build", shard=i,
+                           rows=int(counts[:, i].sum())), sh._on_device():
+                    cols = [jnp.concatenate(c) if len(c) > 1 else c[0]
+                            for c in zip(*parts)]
+                    del parts
+                    raw, paas, codes, ids = cols
+                    del cols
+                    tree = T.build(raw, self.cfg, leaf_size=self.leaf_size,
+                                   materialized=self.materialized,
+                                   timestamps=ids, ids=ids, paas=paas,
+                                   codes=codes)
+                    del raw, paas, codes, ids
+                    sh.load_run(jax.block_until_ready(tree))
+
+            with self._state_lock:
+                self._epoch += 1          # odd: runs being published
+            try:
+                if self._pool is not None and shards[0].device is not None:
+                    # each shard on its own chip at once; a copy of this
+                    # context each keeps the spans under ``shard.load``
+                    for f in [self._pool.submit(
+                            contextvars.copy_context().run, build, i)
+                            for i in range(n_sh)]:
+                        f.result()
+                else:
+                    for i in range(n_sh):
+                        build(i)
+            finally:
+                with self._state_lock:
+                    self._next_id = total
+                    self.clock = max(self.clock, total)
+                    self._epoch += 1      # even: every shard published
+            for i, s in enumerate(shards):
+                s.advance_clock(self.clock)
+                reg.counter(f"shard.s{i}.rows_total").inc(
+                    int(counts[:, i].sum()))
+                reg.gauge(f"shard.s{i}.size_rows").set(s.n)
 
     def _wait_budget(self) -> None:
         """Shared backpressure: block while the TOTAL compaction debt
@@ -478,6 +651,7 @@ class ShardedCoconutLSM:
                                wal_fsync=self.wal_fsync,
                                max_debt=self.max_debt,
                                tiers=self.tiers))
+            self._place(new_shards)
             # detach the fill-phase WALs: the OLD generation stays the
             # authoritative durable copy until the SHARDS.json switch (a
             # crash before it orphans the new dirs entirely), so logging +
@@ -678,9 +852,9 @@ class ShardedCoconutLSM:
         prune+verify+top-k+merge pass; buffers are brute-forced host
         side first and their k-th distances seed the launch bound), and
         transparently falls back to the threaded fan-out whenever the
-        batch cannot run on device — budgeted/approx probes, snapshots
-        whose ids/timestamps do not fit the pinned int32 columns, or a
-        pin-budget miss — so answers stay exact either way.
+        batch cannot run on device — budgeted/approx probes, runs
+        without ids, window cuts past int32, or a pin-budget miss — so
+        answers stay exact either way.
 
         ``budget`` / ``mode="approx"``: the global
         :class:`repro.query.Budget` is *split* across shards — each
@@ -728,6 +902,14 @@ class ShardedCoconutLSM:
             return self._fanout(queries, rec, k=k, window=window,
                                 radius_leaves=radius_leaves,
                                 budget=budget, approx=approx)
+
+    def pin_mesh(self):
+        """Pin the current snapshot's runs for the mesh scan now, as the
+        first mesh probe would (a server warms up with it).  Returns the
+        pinned generation (:class:`repro.query.mesh.PinnedShards`), or
+        None where the runs cannot be pinned."""
+        snaps, _, _ = self._snapshots()
+        return self._mesh_engine_get().pin(snaps)
 
     def _mesh_engine_get(self):
         """The lazily-built :class:`~repro.query.mesh.MeshScanEngine`,
@@ -778,8 +960,7 @@ class ShardedCoconutLSM:
                 return None
             ts_min = np.clip(ts_min, np.iinfo(np.int32).min,
                              np.iinfo(np.int32).max).astype(np.int32)
-        q_paas = np.asarray(S.paa(jnp.asarray(queries),
-                                  self.cfg.segments))
+        q_paas = S.paa(jnp.asarray(queries), self.cfg.segments)
         stats = T.SearchStats(candidates=0, exact=True, queries=nq)
         info = {"partitions_touched": 0, "partitions_pruned": 0,
                 "buffer_rows": 0}
@@ -819,7 +1000,7 @@ class ShardedCoconutLSM:
                    sub_shards=pinned.layout.shards_per_device,
                    queries=nq, rows=sum(pinned.rows)) as msp:
             d, ids64, counts = eng.launch(pinned, queries, q_paas,
-                                          ts_min, bound, k=k)
+                                          ts_min, bound, k=k, stats=stats)
             msp.set(candidates=int(counts.sum()))
         best_d, best_off = merge_pools(best_d, best_off, d, ids64, k)
 
@@ -857,6 +1038,7 @@ class ShardedCoconutLSM:
                     stats=stats)
         info["scan_mode"] = "mesh"
         info["mesh_devices"] = pinned.layout.n_devices
+        info["mesh_rows"] = sum(pinned.rows)
         rec["stats"] = stats
         rec["scan_mode"] = "mesh"
         rec["mesh_devices"] = pinned.layout.n_devices

@@ -81,7 +81,8 @@ def _build_launch(mesh, axis: str, cfg: S.SummaryConfig, k: int,
     scale = cfg.series_len / cfg.segments
     lower, upper = _finite_bounds(cfg.bits)
 
-    def body(codes, raw, ids, ts, ts_min, queries, q_paas, bound):
+    # named for the program the device trace shows: ``jit_mesh_scan``
+    def mesh_scan(codes, raw, ids, ts, ts_min, queries, q_paas, bound):
         # per-device block: codes [spd, cap, w], raw [spd, cap, L],
         # ids/ts [spd, cap], ts_min [spd]; query inputs replicated
         spd, cap = ids.shape
@@ -123,7 +124,7 @@ def _build_launch(mesh, axis: str, cfg: S.SummaryConfig, k: int,
         return out_d, out_i, counts
 
     fn = jax.shard_map(
-        body, mesh=mesh,
+        mesh_scan, mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None, None),
                   P(axis, None), P(axis, None), P(axis),
                   P(None, None), P(None, None), P(None)),

@@ -15,8 +15,8 @@ import os
 
 import jax
 
-__all__ = ["make_production_mesh", "make_scan_mesh", "dp_axes",
-           "fsdp_axes", "tp_axis"]
+__all__ = ["make_production_mesh", "make_scan_mesh", "scan_devices",
+           "shard_device", "dp_axes", "fsdp_axes", "tp_axis"]
 
 SCAN_AXIS = "shard"
 
@@ -41,12 +41,11 @@ def make_production_mesh(*, multi_pod: bool = False):
         np.asarray(devices[:n]).reshape(shape), axes)
 
 
-def make_scan_mesh(n_shards: int, *, axis: str = SCAN_AXIS):
-    """1-D mesh for the device-resident sharded scan of ``n_shards``.
-
-    Spans D devices where D is the largest divisor of ``n_shards`` that
+def scan_devices(n_shards: int) -> list:
+    """The D devices that ``n_shards`` key-range shards live on and the
+    sharded scan spans: D is the largest divisor of ``n_shards`` that
     fits the available devices, so the pinned ``[S, cap, ...]`` stacks
-    always shard evenly (each device scans ``S/D`` sub-shards; with one
+    always shard evenly (each device holds ``S/D`` shards; with one
     device every shard count degenerates to a single-device launch).
     The ``COCONUT_MESH_DEVICES`` env var caps D below the physical
     device count (ops/bench knob for device-scaling sweeps).
@@ -57,10 +56,27 @@ def make_scan_mesh(n_shards: int, *, axis: str = SCAN_AXIS):
     cap = int(os.environ.get("COCONUT_MESH_DEVICES", "0") or 0)
     if cap > 0:
         devices = devices[:cap]
-    import numpy as np
     d = max(x for x in range(1, min(n_shards, len(devices)) + 1)
             if n_shards % x == 0)
-    return jax.sharding.Mesh(np.asarray(devices[:d]), (axis,))
+    return list(devices[:d])
+
+
+def shard_device(i: int, n_shards: int):
+    """The device of shard ``i`` of ``n_shards``: ``devices[i * D // S]``
+    over :func:`scan_devices`.  The sharded engine keeps every array of
+    shard ``i``'s runs there, and :func:`make_scan_mesh` puts the same
+    device at the shard's place in the mesh, so a pinned block is built
+    where its rows already are."""
+    devices = scan_devices(n_shards)
+    return devices[i * len(devices) // n_shards]
+
+
+def make_scan_mesh(n_shards: int, *, axis: str = SCAN_AXIS):
+    """1-D mesh over :func:`scan_devices` for the device-resident
+    sharded scan of ``n_shards``: mesh position ``d`` holds shards
+    ``d*S/D .. (d+1)*S/D - 1``, each on its :func:`shard_device`."""
+    import numpy as np
+    return jax.sharding.Mesh(np.asarray(scan_devices(n_shards)), (axis,))
 
 
 def make_host_mesh():

@@ -5,9 +5,16 @@ The residency half of the device-resident sharded scan
 
 * **Pinning** — stacking every shard's immutable run columns (SAX codes,
   raw series, global ids, timestamps) into ``[S, cap, ...]`` arrays
-  padded to a bucket-rounded capacity and ``device_put`` with a
-  ``PartitionSpec('shard', ...)`` layout on a 1-D scan mesh, so a probe
-  batch launches with zero host->device column traffic.
+  padded to a bucket-rounded capacity, sharded ``PartitionSpec('shard',
+  ...)`` over a 1-D scan mesh, so a probe batch launches with zero
+  host->device column traffic.  Each device's block is built on that
+  device from its shards' device columns (``jnp`` concatenation and
+  padding, ids of -1 for padding) and the blocks are assembled into the
+  stacks in place (``jax.make_array_from_single_device_arrays``): the
+  sharded engine keeps shard ``i`` on mesh device ``i * D // S``
+  (``launch.mesh.shard_device``), so no row moves and none passes
+  through the host.  Columns that live elsewhere (an engine reopened on
+  one device) are copied to their mesh device first.
 * **Freshness** — a per-snapshot fingerprint ``(id(run.tree), rows,
   segment)`` per shard.  Runs are immutable once published, so any
   flush, merge, or rebalance yields a different run tuple and the next
@@ -28,22 +35,18 @@ insert) are scanned host-side by the caller first, and their k-th
 distances seed the launch ``bound`` — the same bsf-chaining the
 threaded fan-out applies across shards, applied across the whole mesh.
 
-Bit-parity protocol: the repo's canonical distance bits are those of
-``summarization.sum_sq``, which adds a row's squares in one fixed
-pairwise order of elementwise ops, so the bits never depend on the
-shape a row was verified in.  The launch adds in that order too, but
-its on-device distances are still treated as *selection* scores only:
-after the launch picks each query's top-k rows,
-:meth:`MeshScanEngine.launch` re-verifies exactly those rows with the
-eager verifier (``summarization.sq_dist``), so the values are the ones
-the threaded executor returns for the same rows whatever the kernel's
-compiler did.  Selection itself can only differ from the threaded path
-when two rows' true distances sit within one ulp — the same
-measure-zero tie class both paths already carry.
+Answers: every distance the repo returns adds a row's squares in one
+fixed pairwise order (``summarization.sum_sq``), the launch's bodies
+(the jnp twin and the Pallas ``scan_verify``) included, so a row's
+distance has the same bits whichever path verified it.  The launch's
+distances and ids are therefore the answer as they come off the
+device; its three outputs are read through ``merger.to_host`` (one
+``exec.sync`` span and one ``SearchStats.device_syncs`` count each).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Optional, Sequence, Tuple
 
@@ -56,17 +59,16 @@ from ..core import summarization as S
 from ..kernels import ops
 from ..launch.mesh import SCAN_AXIS, make_scan_mesh
 from ..obs import get_registry, span as _span
+from .merger import SearchStats, to_host
 from .planner import DeviceLayout, build_device_layout
 
 __all__ = ["MeshScanEngine", "PinnedShards"]
 
-_I32 = np.iinfo(np.int32)
-
 
 @dataclasses.dataclass(frozen=True)
 class PinnedShards:
-    """One immutable pinned generation: the device mirror of one exact
-    run-set.  Strong ``runs`` refs keep every mirrored tree alive so the
+    """One immutable pinned generation: the device columns of one exact
+    run-set.  Strong ``runs`` refs keep every pinned tree alive so the
     fingerprint's ``id()`` components stay unambiguous."""
     fingerprint: tuple
     layout: DeviceLayout
@@ -80,12 +82,30 @@ class PinnedShards:
     leaves: Tuple[int, ...]        # per-shard pinned leaf counts
     runs: tuple
     nbytes: int
-    # host mirror for the eager re-verification of selected candidates
-    # (+ the id -> flat-slot lookup): [S*cap, L] rows, ids sorted with
-    # their argsort so a searchsorted maps global id -> pinned slot
-    host_raw: np.ndarray
-    ids_sorted: np.ndarray
-    id_order: np.ndarray
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "fill"))
+def _pinned_block(shards: tuple, *, cap: int, fill):
+    """``[spd, cap, ...]``: each shard's run columns (a tuple per
+    shard) concatenated and padded to ``cap`` rows with ``fill``, one
+    program and one buffer on the device that holds them."""
+    out = []
+    for runs in shards:
+        x = jnp.concatenate(runs) if len(runs) > 1 else runs[0]
+        pad = [(0, cap - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+        out.append(jnp.pad(x, pad, constant_values=fill))
+    return jnp.stack(out)
+
+
+def _run_columns(tree):
+    """(codes, raw, ids, ts) of a run as device arrays (ids and
+    timestamps are int32 on the device); missing timestamps read 0."""
+    from ..core.tree import take_rows
+    raw = (tree.raw if tree.raw is not None
+           else take_rows(tree.raw_ref, tree.offsets))
+    ts = (jnp.zeros(tree.n, jnp.int32) if tree.timestamps is None
+          else tree.timestamps)
+    return tree.codes, raw, tree.ids, ts
 
 
 class MeshScanEngine:
@@ -137,11 +157,11 @@ class MeshScanEngine:
                      for sn in snaps)
 
     def pin(self, snaps: Sequence) -> Optional[PinnedShards]:
-        """The pinned generation mirroring ``snaps`` (one Snapshot per
-        shard), rebuilding if any shard's run set changed.  Returns
-        None when the snapshot cannot be pinned (ids missing or outside
-        int32, or the pin budget would be exceeded) — the caller must
-        fall back to the threaded path."""
+        """The pinned generation of ``snaps`` (one Snapshot per shard),
+        rebuilding if any shard's run set changed.  Returns None when
+        the snapshot cannot be pinned (a run without ids, or the pin
+        budget would be exceeded) — the caller must fall back to the
+        threaded path."""
         fp = self._fingerprint(snaps)
         with self._lock:
             cur = self._pinned
@@ -159,85 +179,72 @@ class MeshScanEngine:
                fp: tuple) -> Optional[PinnedShards]:
         w, L = self.cfg.segments, self.cfg.series_len
         with _span("mesh_pin", shards=len(snaps)):
-            shards, runs, has_ts = [], [], True
+            shards, runs, has_ts, leaves = [], [], True, []
             for sn in snaps:
-                codes_l, raw_l, ids_l, ts_l, leaves = [], [], [], [], 0
+                cols, lv = [], 0
                 for r in sn.runs:
-                    t = r.tree
-                    if t.ids is None:
+                    if r.tree.ids is None:
                         return None
-                    ids_np = np.asarray(t.ids)
-                    if ids_np.size and (int(ids_np.min()) < 0
-                                        or int(ids_np.max()) > _I32.max):
-                        return None
-                    codes_l.append(np.asarray(t.codes, np.uint8))
-                    if t.raw is not None:
-                        raw_np = np.asarray(t.raw, np.float32)
-                    else:
-                        raw_np = np.asarray(t.raw_ref, np.float32)[
-                            np.asarray(t.offsets)]
-                    raw_l.append(raw_np)
-                    ids_l.append(ids_np.astype(np.int32))
-                    if t.timestamps is None:
-                        has_ts = False
-                        ts_l.append(np.zeros(t.n, np.int32))
-                    else:
-                        ts_l.append(np.asarray(t.timestamps, np.int32))
-                    leaves += t.n_leaves
+                    has_ts = has_ts and r.tree.timestamps is not None
+                    cols.append(_run_columns(r.tree))
+                    lv += r.tree.n_leaves
                     runs.append(r)
-                shards.append((codes_l, raw_l, ids_l, ts_l, leaves))
-            row_counts = [sum(len(i) for i in sh[2]) for sh in shards]
+                shards.append(cols)
+                leaves.append(lv)
+            row_counts = [sum(int(c[2].shape[0]) for c in cols)
+                          for cols in shards]
             mesh = make_scan_mesh(len(snaps), axis=self.axis)
             layout = build_device_layout(
                 row_counts, n_devices=mesh.devices.size,
                 bucket=self.bucket)
             s, cap = layout.n_shards, layout.cap
+            spd = layout.shards_per_device
             nbytes = s * cap * (w + 4 * L + 4 + 4)
             if self.max_pin_bytes is not None \
                     and nbytes > self.max_pin_bytes:
                 return None
-            codes = np.zeros((s, cap, w), np.uint8)
-            raw = np.zeros((s, cap, L), np.float32)
-            ids = np.full((s, cap), -1, np.int32)
-            ts = np.zeros((s, cap), np.int32)
-            for si, (codes_l, raw_l, ids_l, ts_l, _lv) in \
-                    enumerate(shards):
-                at = 0
-                for c, rw, i, tcol in zip(codes_l, raw_l, ids_l, ts_l):
-                    n = len(i)
-                    codes[si, at:at + n] = c
-                    raw[si, at:at + n] = rw
-                    ids[si, at:at + n] = i
-                    ts[si, at:at + n] = tcol
-                    at += n
-            spec3 = NamedSharding(mesh, P(self.axis, None, None))
-            spec2 = NamedSharding(mesh, P(self.axis, None))
-            host_raw = raw.reshape(s * cap, L)
-            ids_flat = ids.reshape(s * cap).astype(np.int64)
-            id_order = np.argsort(ids_flat, kind="stable")
+            empty = (np.zeros((0, w), np.uint8),
+                     np.zeros((0, L), np.float32),
+                     np.zeros(0, np.int32), np.zeros(0, np.int32))
+            blocks = ([], [], [], [])
+            for d, dev in enumerate(mesh.devices.flat):
+                # the device's shards: each column of each run there, a
+                # no-op put where the shard already lives on ``dev``
+                mine = [[jax.device_put(c, dev) for c in cols]
+                        if cols else [jax.device_put(empty, dev)]
+                        for cols in shards[d * spd:(d + 1) * spd]]
+                for col, fill in enumerate((0, 0.0, -1, 0)):
+                    blocks[col].append(_pinned_block(
+                        tuple(tuple(c[col] for c in runs_)
+                              for runs_ in mine), cap=cap, fill=fill))
+
+            def stack(col, tail):
+                spec = NamedSharding(mesh, P(self.axis,
+                                             *([None] * len(tail))))
+                return jax.make_array_from_single_device_arrays(
+                    (s, cap) + tail, spec, blocks[col])
+
             return PinnedShards(
                 fingerprint=fp, layout=layout, mesh=mesh,
-                codes=jax.device_put(codes, spec3),
-                raw=jax.device_put(raw, spec3),
-                ids=jax.device_put(ids, spec2),
-                ts=jax.device_put(ts, spec2),
-                has_ts=has_ts,
-                rows=tuple(row_counts),
-                leaves=tuple(sh[4] for sh in shards),
-                runs=tuple(runs), nbytes=nbytes,
-                host_raw=host_raw,
-                ids_sorted=ids_flat[id_order], id_order=id_order)
+                codes=stack(0, (w,)), raw=stack(1, (L,)),
+                ids=stack(2, ()), ts=stack(3, ()), has_ts=has_ts,
+                rows=tuple(row_counts), leaves=tuple(leaves),
+                runs=tuple(runs), nbytes=nbytes)
 
     # ---------------------------------------------------------------- launches
     def launch(self, pinned: PinnedShards, queries: np.ndarray,
-               q_paas: np.ndarray, ts_min: Optional[np.ndarray],
-               bound: np.ndarray, *, k: int, mode: str = "auto"):
+               q_paas, ts_min: Optional[np.ndarray],
+               bound: np.ndarray, *, k: int, mode: str = "auto",
+               stats: Optional[SearchStats] = None):
         """One compiled mesh pass over a pinned generation.
 
-        ``ts_min`` is the per-shard ``[S]`` int32 visibility cut or
-        None; ``bound`` the per-query strict bsf (inf = unbounded) from
-        the host-side buffer pool.  Returns host (dists [Q, k] f32,
-        global ids [Q, k] int64 with -1 padding, counts [S, Q] int64).
+        ``q_paas`` is the queries' ``[Q, w]`` PAA (a device array
+        stays on the device); ``ts_min`` the per-shard ``[S]`` int32
+        visibility cut or None; ``bound`` the per-query strict bsf (inf
+        = unbounded) from the host-side buffer pool.  Returns host
+        (dists [Q, k] f32, global ids [Q, k] int64 with -1 padding,
+        counts [S, Q] int64), each read through ``merger.to_host`` and
+        counted in ``stats``.
         """
         queries = np.atleast_2d(np.asarray(queries, np.float32))
         d, ids32, counts = ops.mesh_scan(
@@ -249,25 +256,9 @@ class MeshScanEngine:
             jnp.asarray(bound, jnp.float32), self.cfg,
             mesh=pinned.mesh, axis=self.axis, k=k, mode=mode)
         self._reg.counter("query.mesh_launches_total").inc()
-        d = np.asarray(d).copy()
-        ids64 = np.asarray(ids32, np.int64)
-        # canonical bits: the launch SELECTED these rows; their reported
-        # distances are re-verified with the eager op chain (the bits
-        # every threaded entry point returns — see module docstring)
-        valid = ids64 >= 0
-        if valid.any():
-            qi, _ki = np.nonzero(valid)
-            pos = np.searchsorted(pinned.ids_sorted, ids64[valid])
-            slot = pinned.id_order[pos]
-            rows = jnp.asarray(pinned.host_raw[slot])
-            d[valid] = np.asarray(S.sq_dist(rows, jnp.asarray(queries[qi])),
-                                  np.float32)
-            # keep each query's pool sorted after the re-verification
-            # (stable: sub-ulp rank flips keep the launch's order)
-            sel = np.argsort(d, axis=1, kind="stable")
-            d = np.take_along_axis(d, sel, axis=1)
-            ids64 = np.take_along_axis(ids64, sel, axis=1)
-        return d, ids64, np.asarray(counts, np.int64)
+        return (to_host(d, stats),
+                to_host(ids32, stats).astype(np.int64),
+                to_host(counts, stats).astype(np.int64))
 
     # ---------------------------------------------------------------- readouts
     @property
